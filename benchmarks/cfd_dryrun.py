@@ -8,7 +8,9 @@ paper's multilevel decomposition n_total = n_nodes x n_GPUs x alpha:
 
 Runs in a subprocess (needs forced host devices before jax import).  Emits
 memory/cost/collective stats like launch/dryrun and appends JSONs to
-results/dryrun/cfd_*.json.
+results/dryrun/cfd_*.json.  CPU-only: a child that needs a chip would find
+it held by the parent, so nothing that runs on the chip calls this
+harness.
 """
 from __future__ import annotations
 

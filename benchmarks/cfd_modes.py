@@ -5,7 +5,9 @@ rendering of "C_i ranks skip the solve"); the full-mesh mode row-shards the
 fused system over the assemble axis too.  Comparison on the production CFD
 mesh (14 solve groups x alpha 15 = 210 devices): per-device solve FLOPs
 should drop ~alpha x in exchange for boundary collective-permutes.
-Subprocess (forced host devices).  Emits both modes' stats.
+Subprocess (forced host devices).  Emits both modes' stats.  CPU-only:
+a child that needs a chip would find it held by the parent, so nothing
+that runs on the chip calls this harness.
 """
 from __future__ import annotations
 

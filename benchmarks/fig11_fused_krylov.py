@@ -1,5 +1,9 @@
 """Fig. 11: fused Krylov iteration core — time/iter and HBM bytes/iter.
 
+CPU-only: each cell is a subprocess on forced host devices (a child
+that needs a chip would find it held by the parent), and nothing that
+runs on the chip calls this harness.
+
 Runs the same diagonally dominant symmetric 7-band system through the CG
 solver on the **reference** SolverOps backend (the seed's jnp op sequence)
 and the **fused** backend (``kernels/krylov_fused``: one-pass SpMV+p.Ap,

@@ -18,7 +18,10 @@ this figure validates the phase split and that both modes converge
 identically (same CG iteration counts).
 
 Each (mode, alpha) cell is a subprocess because the forced device count
-must be set before JAX initializes.
+must be set before JAX initializes.  CPU-only: the subprocesses force
+host devices, and a child that needs a chip would find it held by the
+parent, so nothing that runs on the chip calls this harness
+(``chip_smoke.py --four-chips`` is the four-chip path).
 """
 from __future__ import annotations
 

@@ -1,57 +1,14 @@
-"""JAX version compatibility shims (single choke point, no hard pins).
+"""JAX version compatibility shim (single choke point).
 
-Two APIs we depend on moved or changed shape across the JAX versions this
-repo runs under:
-
-* ``shard_map`` — new JAX exposes ``jax.shard_map`` (with a ``check_vma``
-  kwarg); older releases only have ``jax.experimental.shard_map.shard_map``
-  (same semantics, the kwarg is spelled ``check_rep``).  Every call site
-  (``repro.training.pipeline``, ``repro.sparse.shardmap_spmv``) imports the
-  shim from here so the fallback logic exists exactly once.
-* ``Compiled.cost_analysis()`` — returns a dict of metrics on some versions
-  and a list with one dict per device/program on others.
-  :func:`cost_analysis_dict` normalizes both to a plain dict.
+``Compiled.cost_analysis()`` returns the metrics dict, or ``None`` where a
+backend reports nothing; :func:`cost_analysis_dict` turns the latter into
+an empty dict so callers can ``.get()`` a metric unconditionally.
 """
 from __future__ import annotations
 
-import jax
-
-__all__ = ["shard_map", "cost_analysis_dict"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with a fallback to the pre-export experimental API.
-
-    Accepts the modern keyword ``check_vma`` (varying-manual-axes check);
-    on older JAX it is forwarded as ``check_rep``, the previous name for
-    the same replication-consistency check.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+__all__ = ["cost_analysis_dict"]
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict on every JAX version.
-
-    Newer JAX returns the metrics dict directly; older versions wrap it in a
-    per-program list (usually length 1 — multiple entries are summed, which
-    matches how callers use the totals).
-    """
-    cost = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, dict):
-        return cost
-    total: dict = {}
-    for entry in cost:
-        for key, val in entry.items():
-            if isinstance(val, (int, float)):
-                total[key] = total.get(key, 0.0) + val
-            else:
-                total.setdefault(key, val)
-    return total
+    """``compiled.cost_analysis()`` as a dict (empty when unreported)."""
+    return compiled.cost_analysis() or {}

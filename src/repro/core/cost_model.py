@@ -26,6 +26,7 @@ from typing import ClassVar
 
 __all__ = [
     "HardwareSpec", "CostModel", "PhaseBreakdown", "TPU_V5E", "HOREKA_A100",
+    "TPU_SPECS", "hardware_for",
 ]
 
 
@@ -56,6 +57,28 @@ TPU_V5E = HardwareSpec(
     host_flops=3e9 * 8, host_bw=30e9, h2d_bw=16e9,
     dofs_sat=1e6, oversub_penalty=0.0,  # SPMD: no rank contention
 )
+
+# priced TPU devices by ``jax.Device.device_kind`` (v5e reports itself
+# as "TPU v5 lite"); a TPU kind missing here is an error, not a default
+TPU_SPECS: dict[str, HardwareSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device) -> HardwareSpec:
+    """The :class:`HardwareSpec` that prices ``device`` (a ``jax.Device``).
+
+    TPU devices are looked up by ``device_kind`` in :data:`TPU_SPECS` and
+    an unknown kind raises; any other platform (the CPU test runs) keeps
+    the explicit v5e prior.
+    """
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return TPU_SPECS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for TPU kind {device.device_kind!r} "
+            f"(priced: {sorted(TPU_SPECS)})") from None
+
 
 HOREKA_A100 = HardwareSpec(
     name="horeka_a100",
@@ -150,14 +173,17 @@ class CostModel:
     # Mixed-precision Krylov policy (repro.solvers.precision): the inner
     # sweeps stream bands + vectors at the policy's *storage* width
     # (f32_ir: 4 B, bf16_ir: 2 B — the near-2x/4x bandwidth lever on a
-    # bandwidth-bound solver), plus ``refine_outers`` f64 residual-replay
-    # passes (one full-width SpMV + correction axpy each).  Under the
+    # bandwidth-bound solver), plus ``refine_outers`` f64 outer passes
+    # (two full-width SpMVs + the correction axpy each).  Under the
     # default "f64" policy the bytes expression is exactly the pre-policy
     # one.  ``solver_iters`` counts *inner* iterations for refined
     # policies (the inner/outer split the controller's alpha selection
     # sees).
     precision: str = "f64"
     refine_outers: int = 4
+    # a refined CG sweep deflates the constant vector: one more dot per
+    # inner iteration (a1 . z), two more vector transits
+    deflation_passes: float = 2.0
     # Host→XLA launch overhead per *dispatched* step.  The StepProgram's
     # scan-rolled executor (fvm/step_program.FusedExecutor.run_steps)
     # retires this term: a window of n timesteps is ONE executable launch,
@@ -197,15 +223,16 @@ class CostModel:
                 * self.bytes_per_val
             return per_iter * self.solver_iters
         # refined policy: inner sweeps at the storage width, plus
-        # refine_outers full-width replay passes (bands + x read, r
-        # written, correction axpy: ~nnz + 3 vector transits each)
+        # refine_outers full-width outer passes
         from repro.solvers.precision import get_policy
 
         pol = get_policy(self.precision)
-        inner = (self.nnz_per_row + vec) * self.n_dofs \
-            * pol.storage_itemsize * self.solver_iters
-        outer = (self.nnz_per_row + 3) * self.n_dofs * self.bytes_per_val \
-            * self.refine_outers
+        inner = (self.nnz_per_row + vec + self.deflation_passes) \
+            * self.n_dofs * pol.storage_itemsize * self.solver_iters
+        # per outer pass two f64 SpMVs (A d, then the replay b - A x) and
+        # the correction axpy: ~2 nnz + 5 vector transits
+        outer = (2 * self.nnz_per_row + 5) * self.n_dofs \
+            * self.bytes_per_val * self.refine_outers
         return inner + outer
 
     def t_solve_core(self, n_dev: int, ranks_per_dev: int = 1) -> float:

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.repartition import RepartitionPlan
+from repro.core.rows import take_rows
 
 __all__ = [
     "concat_group_buffers",
@@ -51,14 +52,14 @@ def concat_group_buffers(buffers: jax.Array) -> jax.Array:
 
 def ell_values(plan: RepartitionPlan, buf_cat: jax.Array) -> jax.Array:
     """Apply P∘U: (n_coarse, alpha*L+1) → ELL values (n_coarse, m_c, K)."""
-    return jnp.take(buf_cat, plan.ell_src.reshape(-1), axis=1).reshape(
+    return take_rows(buf_cat, plan.ell_src.reshape(-1)).reshape(
         buf_cat.shape[0], plan.m_coarse, plan.K)
 
 
 def dia_values(plan: RepartitionPlan, buf_cat: jax.Array) -> jax.Array:
     """Apply P∘U: (n_coarse, alpha*L+1) → DIA bands (n_coarse, n_bands, m_c)."""
     nb = len(plan.dia_offsets)
-    return jnp.take(buf_cat, plan.dia_src.reshape(-1), axis=1).reshape(
+    return take_rows(buf_cat, plan.dia_src.reshape(-1)).reshape(
         buf_cat.shape[0], nb, plan.m_coarse)
 
 
@@ -121,7 +122,7 @@ def _pooled_update(schedule: str):
         if schedule == "host_buffer":
             buffers = jax.lax.optimization_barrier(buffers)
         buf_cat = concat_group_buffers(buffers)
-        return jnp.take(buf_cat, src.reshape(-1), axis=1).reshape(
+        return take_rows(buf_cat, src.reshape(-1)).reshape(
             buf_cat.shape[0], *src.shape)
     return jax.jit(fn)
 

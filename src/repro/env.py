@@ -27,7 +27,14 @@ import os
 import sys
 
 __all__ = ["GPU_XLA_FLAGS", "configure_platform", "enable_x64",
-           "jax_initialized"]
+           "enable_compile_cache", "jax_initialized", "COMPILE_CACHE_DIR"]
+
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+# unset: a fixed directory of the checkout (listed in .gitignore), so a
+# later process on the same checkout finds what an earlier one compiled
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 # The overlap-relevant XLA tuning set (GPU backend).  The latency-hiding
 # scheduler + async/priority-stream flags are what let the pipelined
@@ -78,6 +85,23 @@ def enable_x64() -> None:
     import jax
 
     jax.config.update("jax_enable_x64", True)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as it stands (JAX
+    reads it itself) and no other directory is set in code; otherwise the
+    cache sits at :data:`COMPILE_CACHE_DIR`.  Every launcher and the chip
+    smoke call this one helper.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _flag_name(flag: str) -> str:

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.fvm.cases import (FlowCase, INLET, MOVING_WALL, OUTLET, PatchBC,
                              get_case)
+from repro.core.rows import add_rows, take_rows
 from repro.fvm.mesh import CavityMesh, DOWN, UP
 from repro.sparse.distributed import halo_exchange
 
@@ -208,17 +209,16 @@ class CavityAssembly:
         phi_f = 0.5*(U_o + U_n)[axis] * A, oriented owner→neigh.  Interface
         fluxes are *outward* of the owning part (down: -z, up: +z).
         """
-        Uo = U[:, self.owner, :]
-        Un = U[:, self.neigh, :]
-        Uf = 0.5 * (Uo + Un)
-        comp = jnp.take_along_axis(
-            Uf, self.face_axis[None, :, None].astype(jnp.int32), axis=2)[..., 0]
+        Uf = 0.5 * (take_rows(U, self.owner) + take_rows(U, self.neigh))
+        ax = self.face_axis
+        comp = jnp.where(ax == 0, Uf[..., 0],
+                         jnp.where(ax == 1, Uf[..., 1], Uf[..., 2]))
         phi = comp * self.A
         # interface: halo of w-velocity planes
         w = U[..., 2]
         down, up = halo_exchange(w, self.plane)  # remote plane values
-        w_down_local = w[:, self.if_rows[DOWN]]
-        w_up_local = w[:, self.if_rows[UP]]
+        w_down_local = take_rows(w, self.if_rows[DOWN])
+        w_up_local = take_rows(w, self.if_rows[UP])
         phi_down = -self.A * 0.5 * (w_down_local + down)   # outward -z
         phi_up = +self.A * 0.5 * (w_up_local + up)         # outward +z
         phi_if = jnp.stack([phi_down, phi_up], axis=1) * self.if_mask
@@ -242,7 +242,7 @@ class CavityAssembly:
             mask = self.patch_mask[:, pi]
             nz = self._patch_nz[pi]
             if self.patch_kind[pi] == OUTLET:
-                f = U[:, rows, 2] * (nz * self.A)
+                f = take_rows(U[..., 2], rows) * (nz * self.A)
             else:
                 w = jnp.atleast_2d(self.patch_Ub[pi])[:, 2]  # (1,) or (B,)
                 f = jnp.broadcast_to(w * (nz * self.A), (P, self.plane))
@@ -256,17 +256,19 @@ class CavityAssembly:
         """(P, m, 3) Gauss gradient of a cell scalar field."""
         P, m = p.shape
         g = jnp.zeros((P, m, 3), self.dtype)
-        pf = 0.5 * (p[:, self.owner] + p[:, self.neigh])  # (P, F)
+        pf = 0.5 * (take_rows(p, self.owner) + take_rows(p, self.neigh))
         sf = jax.nn.one_hot(self.face_axis, 3, dtype=self.dtype) * self.A  # (F,3)
         contrib = pf[:, :, None] * sf[None, :, :]
-        g = g.at[:, self.owner, :].add(contrib)
-        g = g.at[:, self.neigh, :].add(-contrib)
+        g = add_rows(g, self.owner, contrib)
+        g = add_rows(g, self.neigh, -contrib)
         # interfaces: S = ±A e_z outward
         down, up = halo_exchange(p, self.plane)
-        pf_down = 0.5 * (p[:, self.if_rows[DOWN]] + down) * self.if_mask[:, DOWN]
-        pf_up = 0.5 * (p[:, self.if_rows[UP]] + up) * self.if_mask[:, UP]
-        g = g.at[:, self.if_rows[DOWN], 2].add(-self.A * pf_down)
-        g = g.at[:, self.if_rows[UP], 2].add(self.A * pf_up)
+        pf_down = (0.5 * (take_rows(p, self.if_rows[DOWN]) + down)
+                   * self.if_mask[:, DOWN])
+        pf_up = 0.5 * (take_rows(p, self.if_rows[UP]) + up) * self.if_mask[:, UP]
+        gz = add_rows(g[..., 2], self.if_rows[DOWN], -self.A * pf_down)
+        gz = add_rows(gz, self.if_rows[UP], self.A * pf_up)
+        g = g.at[..., 2].set(gz)
         # boundaries: zero-gradient ⇒ p_b = p_owner, S = A n_outward;
         # outlets pin p_b = 0 (Dirichlet), so their face term vanishes
         for rows, mask, kind, patch in zip(self.patch_rows,
@@ -276,8 +278,8 @@ class CavityAssembly:
             if kind == OUTLET:
                 continue
             n = jnp.asarray(patch.normal, self.dtype)
-            pb = p[:, rows] * mask[:, None]
-            g = g.at[:, rows, :].add(pb[:, :, None] * (self.A * n)[None, None, :])
+            pb = take_rows(p, rows) * mask[:, None]
+            g = add_rows(g, rows, pb[:, :, None] * (self.A * n)[None, None, :])
         return g / self.V
 
     def divergence(self, phi: jax.Array, phi_if: jax.Array,
@@ -286,13 +288,13 @@ class CavityAssembly:
         ``phi_b`` adds the z-plane boundary fluxes (inlet/outlet cases)."""
         P = phi.shape[0]
         d = jnp.zeros((P, self.m), self.dtype)
-        d = d.at[:, self.owner].add(phi)
-        d = d.at[:, self.neigh].add(-phi)
-        d = d.at[:, self.if_rows[DOWN]].add(phi_if[:, DOWN])
-        d = d.at[:, self.if_rows[UP]].add(phi_if[:, UP])
+        d = add_rows(d, self.owner, phi)
+        d = add_rows(d, self.neigh, -phi)
+        d = add_rows(d, self.if_rows[DOWN], phi_if[:, DOWN])
+        d = add_rows(d, self.if_rows[UP], phi_if[:, UP])
         if phi_b is not None:
-            d = d.at[:, self.if_rows[DOWN]].add(phi_b[:, DOWN])
-            d = d.at[:, self.if_rows[UP]].add(phi_b[:, UP])
+            d = add_rows(d, self.if_rows[DOWN], phi_b[:, DOWN])
+            d = add_rows(d, self.if_rows[UP], phi_b[:, UP])
         return d
 
     # ------------------------------------------------------------------
@@ -316,12 +318,14 @@ class CavityAssembly:
         iface = jnp.zeros_like(phi_if)
 
         # convection, upwind
-        diag = diag.at[:, self.owner].add(jnp.maximum(phi, 0.0))
+        diag = add_rows(diag, self.owner, jnp.maximum(phi, 0.0))
         upper = upper + jnp.minimum(phi, 0.0)
-        diag = diag.at[:, self.neigh].add(jnp.maximum(-phi, 0.0))
+        diag = add_rows(diag, self.neigh, jnp.maximum(-phi, 0.0))
         lower = lower + jnp.minimum(-phi, 0.0)
-        diag = diag.at[:, self.if_rows[DOWN]].add(jnp.maximum(phi_if[:, DOWN], 0.0))
-        diag = diag.at[:, self.if_rows[UP]].add(jnp.maximum(phi_if[:, UP], 0.0))
+        diag = add_rows(diag, self.if_rows[DOWN],
+                        jnp.maximum(phi_if[:, DOWN], 0.0))
+        diag = add_rows(diag, self.if_rows[UP],
+                        jnp.maximum(phi_if[:, UP], 0.0))
         iface = iface + jnp.minimum(phi_if, 0.0)
 
         # boundary convection (z-plane patches, upwind): outflow convects
@@ -331,21 +335,21 @@ class CavityAssembly:
         if phi_b is not None:
             for slot in (DOWN, UP):
                 rows = self.if_rows[slot]
-                diag = diag.at[:, rows].add(
-                    jnp.maximum(phi_b[:, slot], 0.0))
+                diag = add_rows(diag, rows, jnp.maximum(phi_b[:, slot], 0.0))
                 Ub = self._z_Ub_face(slot)
-                source = source.at[:, rows, :].add(
+                source = add_rows(
+                    source, rows,
                     (-jnp.minimum(phi_b[:, slot], 0.0))[..., None]
                     * Ub[None, :, :])
 
         # diffusion, central
         g = self.nu * self.A / self.h
-        diag = diag.at[:, self.owner].add(g)
-        diag = diag.at[:, self.neigh].add(g)
+        diag = add_rows(diag, self.owner, g)
+        diag = add_rows(diag, self.neigh, g)
         upper = upper - g
         lower = lower - g
-        diag = diag.at[:, self.if_rows[DOWN]].add(g * self.if_mask[:, DOWN])
-        diag = diag.at[:, self.if_rows[UP]].add(g * self.if_mask[:, UP])
+        diag = add_rows(diag, self.if_rows[DOWN], g * self.if_mask[:, DOWN])
+        diag = add_rows(diag, self.if_rows[UP], g * self.if_mask[:, UP])
         iface = iface - g * self.if_mask
 
         # boundary diffusion (Dirichlet walls/lid/inlets, half-cell
@@ -355,8 +359,9 @@ class CavityAssembly:
                                         self.patch_Ub, self.patch_kind):
             if kind == OUTLET:
                 continue
-            diag = diag.at[:, rows].add(gb * mask[:, None])
-            source = source.at[:, rows, :].add(
+            diag = add_rows(diag, rows, gb * mask[:, None])
+            source = add_rows(
+                source, rows,
                 gb * mask[:, None, None] * jnp.atleast_2d(Ub)[None, ...])
 
         # pressure gradient source
@@ -366,11 +371,11 @@ class CavityAssembly:
     def offdiag_apply(self, sys, x: jax.Array) -> jax.Array:
         """y = (A - diag) x on the fine partition (for OpenFOAM's H())."""
         y = jnp.zeros_like(x)
-        y = y.at[:, self.owner].add(sys.upper * x[:, self.neigh])
-        y = y.at[:, self.neigh].add(sys.lower * x[:, self.owner])
+        y = add_rows(y, self.owner, sys.upper * take_rows(x, self.neigh))
+        y = add_rows(y, self.neigh, sys.lower * take_rows(x, self.owner))
         down, up = halo_exchange(x, self.plane)
-        y = y.at[:, self.if_rows[DOWN]].add(sys.iface[:, DOWN] * down)
-        y = y.at[:, self.if_rows[UP]].add(sys.iface[:, UP] * up)
+        y = add_rows(y, self.if_rows[DOWN], sys.iface[:, DOWN] * down)
+        y = add_rows(y, self.if_rows[UP], sys.iface[:, UP] * up)
         return y
 
     # ------------------------------------------------------------------
@@ -389,18 +394,19 @@ class CavityAssembly:
         Returns a :class:`PressureSystem` with a **zero** source.
         """
         P, m = rAU.shape
-        rAUf = 0.5 * (rAU[:, self.owner] + rAU[:, self.neigh])
+        rAUf = 0.5 * (take_rows(rAU, self.owner) + take_rows(rAU, self.neigh))
         g_int = rAUf * self.A / self.h
         down, up = halo_exchange(rAU, self.plane)
-        g_down = 0.5 * (rAU[:, self.if_rows[DOWN]] + down) * self.A / self.h
-        g_up = 0.5 * (rAU[:, self.if_rows[UP]] + up) * self.A / self.h
+        g_down = (0.5 * (take_rows(rAU, self.if_rows[DOWN]) + down)
+                  * self.A / self.h)
+        g_up = 0.5 * (take_rows(rAU, self.if_rows[UP]) + up) * self.A / self.h
         g_if = jnp.stack([g_down, g_up], axis=1) * self.if_mask
 
         diag = jnp.zeros((P, m), self.dtype)
-        diag = diag.at[:, self.owner].add(g_int)
-        diag = diag.at[:, self.neigh].add(g_int)
-        diag = diag.at[:, self.if_rows[DOWN]].add(g_if[:, DOWN])
-        diag = diag.at[:, self.if_rows[UP]].add(g_if[:, UP])
+        diag = add_rows(diag, self.owner, g_int)
+        diag = add_rows(diag, self.neigh, g_int)
+        diag = add_rows(diag, self.if_rows[DOWN], g_if[:, DOWN])
+        diag = add_rows(diag, self.if_rows[UP], g_if[:, UP])
         upper = -g_int
         lower = -g_int
         iface = -g_if
@@ -411,9 +417,9 @@ class CavityAssembly:
             if self.patch_kind[pi] != OUTLET:
                 continue
             rows = self.if_rows[slot]
-            gb = rAU[:, rows] * (self.A / (0.5 * self.h))
+            gb = take_rows(rAU, rows) * (self.A / (0.5 * self.h))
             g_b = g_b.at[:, slot].set(gb * self.patch_mask[:, pi][:, None])
-            diag = diag.at[:, rows].add(g_b[:, slot])
+            diag = add_rows(diag, rows, g_b[:, slot])
 
         if self._needs_ref:
             # reference cell: diag *= (1 + boost) at global cell 0
@@ -449,11 +455,11 @@ class CavityAssembly:
 
     def correct_flux(self, sysP: PressureSystem, phiHbyA, phiHbyA_if, p):
         """phi = phiHbyA - g_f (p_n - p_o); conservative by construction."""
-        dp = p[:, self.neigh] - p[:, self.owner]
+        dp = take_rows(p, self.neigh) - take_rows(p, self.owner)
         phi = phiHbyA - sysP.g_int * dp
         down, up = halo_exchange(p, self.plane)
-        dp_down = down - p[:, self.if_rows[DOWN]]   # outward (-z): remote - local
-        dp_up = up - p[:, self.if_rows[UP]]
+        dp_down = down - take_rows(p, self.if_rows[DOWN])  # outward (-z)
+        dp_up = up - take_rows(p, self.if_rows[UP])
         phi_if = phiHbyA_if - jnp.stack(
             [sysP.g_if[:, DOWN] * dp_down, sysP.g_if[:, UP] * dp_up], axis=1)
         return phi, phi_if * self.if_mask
@@ -468,6 +474,6 @@ class CavityAssembly:
         ``fixedValue`` pressure-flux correction).
         """
         corr = jnp.stack(
-            [sysP.g_b[:, DOWN] * p[:, self.if_rows[DOWN]],
-             sysP.g_b[:, UP] * p[:, self.if_rows[UP]]], axis=1)
+            [sysP.g_b[:, DOWN] * take_rows(p, self.if_rows[DOWN]),
+             sysP.g_b[:, UP] * take_rows(p, self.if_rows[UP])], axis=1)
         return phiHbyA_b + corr

@@ -46,11 +46,9 @@ from repro.fvm.assembly import CavityAssembly
 from repro.fvm.cases import FlowCase, get_case
 from repro.fvm.mesh import CavityMesh
 from repro.fvm.step_program import ProgramExecutors, get_program
-from repro.solvers.jacobi import jacobi_preconditioner
 from repro.solvers.precision import get_policy
 from repro.solvers.ops import (fused_stacked_ops, reference_ops,
-                               resolve_backend)
-from repro.sparse.distributed import spmv_dia
+                               resolve_backend, stacked_reference_ops)
 
 __all__ = ["SegregatedSolver", "PisoSolver", "SimpleSolver", "SOLVERS",
            "make_solver", "PisoState", "StepStats", "stack_states",
@@ -157,9 +155,13 @@ class SegregatedSolver:
     mom_tol: float = 1e-7
     p_tol: float = 1e-8
     # Krylov iteration caps (the silent-divergence knob: a capped exit now
-    # raises StepStats.hit_cap instead of masquerading as convergence)
+    # raises StepStats.hit_cap instead of masquerading as convergence).
+    # None sizes the pressure cap to the mesh: Jacobi-CG needs about 10.5
+    # iterations per cell of the longest edge (361-420 at n = 40, 733-856
+    # at n = 80, 1951-2265 at the 210³ cavity), so the default allows 20
+    # per cell and never fewer than 2000.
     mom_maxiter: int = 500
-    p_maxiter: int = 2000
+    p_maxiter: int | None = None
     update_schedule: str = "device_direct"  # or "host_buffer" (paper fig. 9)
     dtype: jnp.dtype = jnp.float64
     # SPMD solve-phase layout (paper-faithful vs beyond-paper, DESIGN.md §3):
@@ -206,6 +208,9 @@ class SegregatedSolver:
     pipeline: str = "auto"
 
     def __post_init__(self):
+        if self.p_maxiter is None:
+            edge = max(self.mesh.nx, self.mesh.ny, self.mesh.nz)
+            self.p_maxiter = max(2000, 20 * edge)
         if self.mesh.n_parts % self.alpha != 0:
             raise ValueError("alpha must divide the number of fine parts")
         if self.full_mesh_solve and self.solve_mode == "stacked":
@@ -386,6 +391,23 @@ class SegregatedSolver:
         grouped = buffers.reshape(n_c, plan.alpha, plan.buffer_len)
         return self._update(plan, grouped, target="dia")
 
+    def backend_for(self, plan: RepartitionPlan) -> str:
+        """The concrete SolverOps backend of ``plan``'s solve: resolved
+        per part size (``m_coarse`` rows stacked, ``m_coarse / alpha``
+        per full-mesh shard), platform and precision policy."""
+        rows = (plan.m_coarse // plan.alpha if self._use_full_mesh(plan)
+                else plan.m_coarse)
+        return resolve_backend(self.solver_backend, rows,
+                               precision=self.precision)
+
+    def describe_backends(self) -> str:
+        """One line naming every resolved solve backend and the policy."""
+        return (f"solver backends: momentum="
+                f"{self.backend_for(self.plan_mom)} pressure="
+                f"{self.backend_for(self.plan_p)} (requested "
+                f"{self.solver_backend}, {jax.default_backend()}); "
+                f"precision={self.precision}")
+
     def _solver_ops(self, plan: RepartitionPlan, bands, diag):
         """Bind the (bands, diag) system into a SolverOps bundle.
 
@@ -404,8 +426,7 @@ class SegregatedSolver:
                                                     make_jacobi_full_mesh,
                                                     make_spmv_full_mesh)
 
-            backend = resolve_backend(self.solver_backend,
-                                      plan.m_coarse // plan.alpha)
+            backend = self.backend_for(plan)
             diag_c = self._solve_constraint(diag)
             kw = dict(offsets=offsets, plane=plan.plane,
                       n_coarse=self.n_coarse, alpha=plan.alpha,
@@ -418,32 +439,14 @@ class SegregatedSolver:
                 lambda x: fm(bands, x),
                 make_jacobi_full_mesh(self.spmd_mesh, diag_c))
 
-        backend = resolve_backend(self.solver_backend, plan.m_coarse)
+        backend = self.backend_for(plan)
         policy = get_policy(self.precision)
         if backend == "fused":
             return fused_stacked_ops(bands, diag, offsets=offsets,
                                      plane=plan.plane, policy=policy)
 
-        if policy.refine:
-            # inner sweep over downcast bands (the bytes/iter win), outer
-            # f64 residual replay over the originals (the parity gate)
-            bands_lo = bands.astype(policy.storage_dtype)
-            diag_lo = diag.astype(policy.storage_dtype)
-
-            def A_lo(x):
-                return spmv_dia(bands_lo, x, offsets=offsets,
-                                plane=plan.plane)
-
-            def A_hi(x):
-                return spmv_dia(bands, x, offsets=offsets, plane=plan.plane)
-
-            return reference_ops(A_lo, jacobi_preconditioner(diag_lo),
-                                 policy=policy, matvec_hi=A_hi)
-
-        def A(x):
-            return spmv_dia(bands, x, offsets=offsets, plane=plan.plane)
-
-        return reference_ops(A, jacobi_preconditioner(diag))
+        return stacked_reference_ops(bands, diag, offsets=offsets,
+                                     plane=plan.plane, policy=policy)
 
     # ---- the executors ---------------------------------------------------
     @property

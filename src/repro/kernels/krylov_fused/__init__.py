@@ -12,11 +12,13 @@ seed's 6-8 separate XLA ops into two grid passes plus one jnp axpy:
   second pass (five reads, three writes).
 * ``p = z + beta p`` stays a plain jnp axpy (already a single fusion).
 
-Layout contract: same as ``spmv_dia`` — bands ``(nb, m)`` per part walked
-in ``block_rows`` row blocks, ``x_pad = [down-halo | x | up-halo]``
-VMEM-resident across the grid.  Ragged final blocks are zero-padded and
-sliced off; zero pads contribute exactly zero to every block partial, so
-the reductions need no masking.  Each ``pallas_call`` declares its HBM
+Layout contract: same as ``spmv_dia`` — lane-dense ``(rows/128, 128)``
+vectors and bands walked in ``block_rows`` row blocks, the halo'd
+``x_pad`` VMEM-resident across the grid.  The stacked wrappers take
+arrays already laid out (``LaneLayout.pack``; the solver packs once per
+sweep); the ``*_single`` functions take flat ones.  Zero pad rows
+contribute exactly zero to every block partial, so the reductions need
+no masking.  Each ``pallas_call`` declares its HBM
 contract via ``pl.CostEstimate`` (``spmv_dot_cost`` /
 ``fused_axpy_precond_cost``) — the numbers ``Compiled.cost_analysis()``
 reports for the TPU lowering and the numbers

@@ -20,10 +20,15 @@ CG iteration into **two** grid passes plus one trivial axpy:
 The remaining per-iteration work, ``p = z + beta p``, is a single XLA
 fusion already and stays in jnp (``repro.solvers.ops``).
 
-Both wrappers pad a ragged final row block with zeros and slice the tail
-off the outputs — zero band values and zero vector tails contribute exactly
-zero to every partial sum, so no masking is needed (same contract as
-``spmv_dia`` after the ragged-tail fix).
+Both kernels run on the lane-dense ``(rows/128, 128)`` layout of
+``spmv_dia`` (see that module for the band windows and the VMEM budget).
+The block partials are ``(8, 128)`` tiles of sublane-group sums, one tile
+per grid step, finalized by a ``jnp.sum`` outside the kernel; the axpy
+scalar ``alpha`` rides in SMEM.  The ``*_lanes`` cores take one part
+already laid out (the stacked wrappers in ``ops`` vmap them); the flat
+``*_single`` functions lay out per call.  Zero pad rows contribute
+exactly zero to every partial sum, so no masking is needed.  On TPU both refuse 8-byte dtypes
+(:func:`~repro.kernels.spmv_dia.spmv_dia.check_kernel_dtypes`).
 
 Each ``pallas_call`` carries an explicit :class:`pl.CostEstimate` built by
 :func:`spmv_dot_cost` / :func:`fused_axpy_precond_cost`: the kernel's HBM
@@ -40,13 +45,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.spmv_dia.spmv_dia import (  # noqa: F401  (re-exported)
-    DEFAULT_BLOCK_ROWS, pick_block_rows)
+    DEFAULT_BLOCK_ROWS, LANES, _ZERO, LaneLayout, band_call,
+    check_kernel_dtypes, lane_layout, pick_block_rows, tile_sum)
 
 
-def _pad_tail(m: int, block_rows: int) -> int:
-    return (-m) % block_rows
+def _partial_bytes(m: int, block_rows: int, accum_itemsize: int) -> int:
+    """Bytes of the ``(8, 128)`` partial tiles, one per row block."""
+    return -(-m // block_rows) * 8 * LANES * accum_itemsize
 
 
 def spmv_dot_cost(nb: int, m: int, plane: int, itemsize: int = 8,
@@ -55,18 +63,17 @@ def spmv_dot_cost(nb: int, m: int, plane: int, itemsize: int = 8,
     """HBM contract of :func:`spmv_dot_single` (bytes/flops per call).
 
     ``itemsize`` follows the *storage* dtype (bands, vectors); the
-    ``n_blocks`` partial-sum slots are written at ``accum_itemsize``
-    (defaults to the storage width — the uniform-dtype case).
+    partial tiles are written at ``accum_itemsize`` (defaults to the
+    storage width — the uniform-dtype case).
     """
-    n_blocks = -(-m // block_rows)
     acc = accum_itemsize if accum_itemsize is not None else itemsize
     return {
         # bands once + x_pad once (VMEM-resident across the grid) + Ap out
-        # + the n_blocks partial slots (accumulation width)
-        "bytes_accessed": float((nb * m + (m + 2 * plane) + m) * itemsize
-                                + n_blocks * acc),
-        "flops": float(2 * nb * m + 2 * m),
-        "transcendentals": 0.0,
+        # + the partial tiles (accumulation width)
+        "bytes_accessed": ((nb * m + (m + 2 * plane) + m) * itemsize
+                           + _partial_bytes(m, block_rows, acc)),
+        "flops": 2 * nb * m + 2 * m,
+        "transcendentals": 0,
     }
 
 
@@ -74,114 +81,118 @@ def fused_axpy_precond_cost(m: int, itemsize: int = 8,
                             block_rows: int = DEFAULT_BLOCK_ROWS,
                             accum_itemsize: int | None = None) -> dict:
     """HBM contract of :func:`fused_axpy_precond_single`."""
-    n_blocks = -(-m // block_rows)
     acc = accum_itemsize if accum_itemsize is not None else itemsize
     return {
         # reads x, r, p, Ap, inv_diag; writes x', r', z, 2 * partials
-        "bytes_accessed": float((5 * m + 3 * m) * itemsize
-                                + 2 * n_blocks * acc),
-        "flops": float(9 * m),
-        "transcendentals": 0.0,
+        "bytes_accessed": ((5 * m + 3 * m) * itemsize
+                           + 2 * _partial_bytes(m, block_rows, acc)),
+        "flops": 9 * m,
+        "transcendentals": 0,
     }
 
 
 def _cost(d: dict) -> pl.CostEstimate:
-    return pl.CostEstimate(flops=d["flops"],
-                           bytes_accessed=d["bytes_accessed"],
-                           transcendentals=d["transcendentals"])
+    return pl.CostEstimate(**d)
 
 
 # ---------------------------------------------------------------------------
-# kernel 1: SpMV + p.Ap block partials
+# kernel 1: SpMV + p.Ap block partials (the shared spmv_dia band kernel)
 # ---------------------------------------------------------------------------
 
-def _spmv_dot_kernel(bands_ref, xpad_ref, y_ref, dot_ref, *,
-                     offsets: tuple[int, ...], plane: int, block_rows: int,
-                     accum_dtype: str):
-    i = pl.program_id(0)
-    row0 = i * block_rows
-    # low-precision loads, accumulation at the policy's accum dtype (a
-    # no-op upcast when storage == accum, so the f64 path is bit-identical)
-    acc = jnp.zeros((block_rows,), accum_dtype)
-    for d, off in enumerate(offsets):
-        xw = xpad_ref[pl.dslice(row0 + plane + off, block_rows)]
-        acc = acc + bands_ref[d, :].astype(accum_dtype) * xw.astype(accum_dtype)
-    y_ref[:] = acc.astype(y_ref.dtype)
-    # the block's rows of p itself (offset 0 window) feed the p.Ap partial
-    pw = xpad_ref[pl.dslice(row0 + plane, block_rows)]
-    dot_ref[0] = jnp.sum(pw.astype(accum_dtype) * acc)
+def spmv_dot_lanes(bands: jax.Array, x_pad: jax.Array, *,
+                   offsets: tuple[int, ...], layout: LaneLayout,
+                   accum_dtype: str, difference: bool, interpret: bool
+                   ) -> tuple[jax.Array, jax.Array]:
+    """``(A p, p . A p)`` for one part already in ``layout``: bands
+    ``(nb, m_pad/128, 128)``, x_pad ``(x_len/128, 128)``."""
+    nb = bands.shape[0]
+    check_kernel_dtypes(interpret, bands.dtype, accum_dtype)
+    y, partials = band_call(
+        bands, x_pad, offsets=offsets, lead=layout.lead,
+        block_rows=layout.block_rows, accum_dtype=accum_dtype,
+        with_dot=True, difference=difference, interpret=interpret,
+        cost_estimate=_cost(spmv_dot_cost(
+            nb, layout.m_pad, layout.plane, bands.dtype.itemsize,
+            block_rows=layout.block_rows,
+            accum_itemsize=jnp.dtype(accum_dtype).itemsize)))
+    return y, jnp.sum(partials)
 
 
 @functools.partial(jax.jit, static_argnames=("offsets", "plane",
                                              "block_rows", "interpret",
-                                             "accum_dtype"))
+                                             "accum_dtype", "difference"))
 def spmv_dot_single(bands: jax.Array, x_pad: jax.Array, *,
                     offsets: tuple[int, ...], plane: int,
                     block_rows: int = DEFAULT_BLOCK_ROWS,
                     interpret: bool = False,
-                    accum_dtype: str | None = None
+                    accum_dtype: str | None = None,
+                    difference: bool = False
                     ) -> tuple[jax.Array, jax.Array]:
-    """``(A p, p . A p)`` for one part in one grid pass.
+    """``(A p, p . A p)`` for one part in one grid pass, flat in and out.
 
-    bands: (nb, m); x_pad: (m + 2*plane,).  Ragged ``m`` is padded with
-    zeros (zero bands => zero tail contributions to both outputs).
-    ``accum_dtype`` (a dtype *name*, hashable for jit) sets the partial
-    accumulation width; ``None`` accumulates in the storage dtype — the
-    pre-policy behaviour.  ``Ap`` comes back in the storage dtype, the
-    ``p . Ap`` scalar in the accum dtype.
+    bands: (nb, m); x_pad: (m + 2*plane,) (a longer zero tail is
+    ignored).  Ragged ``m`` is padded with zeros (zero bands => zero tail
+    contributions to both outputs).  ``accum_dtype`` (a dtype *name*,
+    hashable for jit) sets the partial accumulation width; ``None``
+    accumulates in the storage dtype.  ``Ap`` comes back in the storage
+    dtype, the ``p . A p`` scalar in the accum dtype.  ``difference``:
+    the bands are in :func:`~repro.sparse.distributed.difference_form`.
     """
-    nb, m = bands.shape
-    assert x_pad.shape == (m + 2 * plane,), (x_pad.shape, m, plane)
-    accum_dtype = accum_dtype or bands.dtype.name
-    acc_itemsize = jnp.dtype(accum_dtype).itemsize
-    pad = _pad_tail(m, block_rows)
-    if pad:
-        bands = jnp.pad(bands, ((0, 0), (0, pad)))
-        x_pad = jnp.pad(x_pad, (0, pad))
-    mp = m + pad
-    grid = (mp // block_rows,)
-    y, partials = pl.pallas_call(
-        functools.partial(_spmv_dot_kernel, offsets=offsets, plane=plane,
-                          block_rows=block_rows, accum_dtype=accum_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((nb, block_rows), lambda i: (0, i)),
-            pl.BlockSpec(x_pad.shape, lambda i: (0,)),  # VMEM-resident
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((mp,), bands.dtype),
-            jax.ShapeDtypeStruct((grid[0],), accum_dtype),
-        ],
-        cost_estimate=_cost(spmv_dot_cost(nb, m, plane, bands.dtype.itemsize,
-                                          block_rows=block_rows,
-                                          accum_itemsize=acc_itemsize)),
-        interpret=interpret,
-    )(bands, x_pad)
-    return y[:m], jnp.sum(partials)
+    m = bands.shape[1]
+    lay = lane_layout(m, plane, block_rows, bands.dtype)
+    y, dot = spmv_dot_lanes(lay.pack(bands), lay.pack_x_pad(x_pad),
+                            offsets=offsets, layout=lay,
+                            accum_dtype=accum_dtype or bands.dtype.name,
+                            difference=difference, interpret=interpret)
+    return lay.unpack(y), dot
 
 
 # ---------------------------------------------------------------------------
 # kernel 2: axpy pair + Jacobi inverse + (r.z, r.r) block partials
 # ---------------------------------------------------------------------------
 
-def _axpy_precond_kernel(x_ref, r_ref, p_ref, ap_ref, inv_ref, alpha_ref,
+def _axpy_precond_kernel(alpha_ref, x_ref, r_ref, p_ref, ap_ref, inv_ref,
                          xo_ref, ro_ref, zo_ref, rz_ref, rr_ref, *,
                          accum_dtype: str):
-    a = alpha_ref[0]
-    xn = x_ref[:] + a * p_ref[:]
-    rn = r_ref[:] - a * ap_ref[:]
-    z = rn * inv_ref[:]
-    xo_ref[:] = xn
-    ro_ref[:] = rn
-    zo_ref[:] = z
+    a = alpha_ref[0].astype(x_ref.dtype)
+    xn = x_ref[...] + a * p_ref[...]
+    rn = r_ref[...] - a * ap_ref[...]
+    z = rn * inv_ref[...]
+    xo_ref[...] = xn
+    ro_ref[...] = rn
+    zo_ref[...] = z
     # the block reductions upcast per element (no-op when storage == accum)
     rn_a = rn.astype(accum_dtype)
-    rz_ref[0] = jnp.sum(rn_a * z.astype(accum_dtype))
-    rr_ref[0] = jnp.sum(rn_a * rn_a)
+    rz_ref[...] = tile_sum(rn_a * z.astype(accum_dtype))
+    rr_ref[...] = tile_sum(rn_a * rn_a)
+
+
+def axpy_precond_lanes(x: jax.Array, r: jax.Array, p: jax.Array,
+                       Ap: jax.Array, inv_diag: jax.Array, alpha: jax.Array,
+                       *, block_rows: int, accum_dtype: str,
+                       interpret: bool):
+    """``(x', r', z, r'.z, r'.r')`` for one part already laid out:
+    vectors ``(rows, 128)`` with ``rows * 128`` a multiple of
+    ``block_rows``."""
+    check_kernel_dtypes(interpret, x.dtype, accum_dtype)
+    rows = x.shape[0]
+    n_blocks = rows * LANES // block_rows
+    blk = pl.BlockSpec((block_rows // LANES, LANES), lambda i: (i, _ZERO))
+    part = pl.BlockSpec((8, LANES), lambda i: (i, _ZERO))
+    xn, rn, z, rz, rr = pl.pallas_call(
+        functools.partial(_axpy_precond_kernel, accum_dtype=accum_dtype),
+        grid=(n_blocks,),
+        in_specs=[pl.BlockSpec((1,), lambda i: (_ZERO,),
+                               memory_space=pltpu.SMEM)] + [blk] * 5,
+        out_specs=[blk, blk, blk, part, part],
+        out_shape=[jax.ShapeDtypeStruct((rows, LANES), x.dtype)] * 3 + [
+            jax.ShapeDtypeStruct((n_blocks * 8, LANES), accum_dtype)] * 2,
+        cost_estimate=_cost(fused_axpy_precond_cost(
+            rows * LANES, x.dtype.itemsize, block_rows=block_rows,
+            accum_itemsize=jnp.dtype(accum_dtype).itemsize)),
+        interpret=interpret,
+    )(jnp.reshape(alpha, (1,)).astype(accum_dtype), x, r, p, Ap, inv_diag)
+    return xn, rn, z, jnp.sum(rz), jnp.sum(rr)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret",
@@ -192,36 +203,19 @@ def fused_axpy_precond_single(x: jax.Array, r: jax.Array, p: jax.Array,
                               block_rows: int = DEFAULT_BLOCK_ROWS,
                               interpret: bool = False,
                               accum_dtype: str | None = None):
-    """``(x', r', z, r'.z, r'.r')`` for one part in one grid pass.
+    """``(x', r', z, r'.z, r'.r')`` for one part in one grid pass, flat.
 
     ``x' = x + alpha p``, ``r' = r - alpha Ap``, ``z = r' * inv_diag``.
-    All inputs (m,); ``alpha`` a scalar.  Ragged ``m`` padded with zeros
-    (zero tails contribute zero to both partials).  Vector outputs stay
-    in the storage dtype; the two partial slots accumulate and return in
-    ``accum_dtype`` (``None``: the storage dtype, pre-policy behaviour).
+    All inputs (m,); ``alpha`` a scalar, held in SMEM at the accum dtype
+    and cast to the storage dtype in the kernel.  Ragged ``m`` padded with
+    zeros (zero tails contribute zero to both partials).  Vector outputs
+    stay in the storage dtype; the two partial tiles accumulate and
+    return in ``accum_dtype`` (``None``: the storage dtype).
     """
     (m,) = x.shape
-    accum_dtype = accum_dtype or x.dtype.name
-    acc_itemsize = jnp.dtype(accum_dtype).itemsize
-    pad = _pad_tail(m, block_rows)
-    vecs = (x, r, p, Ap, inv_diag)
-    if pad:
-        vecs = tuple(jnp.pad(v, (0, pad)) for v in vecs)
-    mp = m + pad
-    grid = (mp // block_rows,)
-    blk = pl.BlockSpec((block_rows,), lambda i: (i,))
-    part = pl.BlockSpec((1,), lambda i: (i,))
-    xn, rn, z, rz, rr = pl.pallas_call(
-        functools.partial(_axpy_precond_kernel, accum_dtype=accum_dtype),
-        grid=grid,
-        in_specs=[blk, blk, blk, blk, blk,
-                  pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=[blk, blk, blk, part, part],
-        out_shape=[jax.ShapeDtypeStruct((mp,), x.dtype)] * 3 + [
-            jax.ShapeDtypeStruct((grid[0],), accum_dtype)] * 2,
-        cost_estimate=_cost(fused_axpy_precond_cost(m, x.dtype.itemsize,
-                                                    block_rows=block_rows,
-                                                    accum_itemsize=acc_itemsize)),
-        interpret=interpret,
-    )(*vecs, jnp.reshape(alpha, (1,)).astype(x.dtype))
-    return xn[:m], rn[:m], z[:m], jnp.sum(rz), jnp.sum(rr)
+    lay = lane_layout(m, 0, block_rows, x.dtype)
+    xn, rn, z, rz, rr = axpy_precond_lanes(
+        *(lay.pack(v) for v in (x, r, p, Ap, inv_diag)), alpha,
+        block_rows=lay.block_rows, accum_dtype=accum_dtype or x.dtype.name,
+        interpret=interpret)
+    return lay.unpack(xn), lay.unpack(rn), lay.unpack(z), rz, rr

@@ -1,11 +1,12 @@
 """Public stacked-part wrappers for the fused Krylov-iteration kernels.
 
-Mirrors the ``spmv_dia`` wrapper conventions: stacked ``(P, ...)`` arrays,
-the halo'd ``x_pad`` built through :func:`repro.sparse.distributed.x_pad`
-(its static part-axis shifts lower to collective-permute under pjit), vmap
-over parts, interpret-mode fallback off-TPU.  The per-part block partials
-are finalized into **global** scalars with a final ``jnp.sum`` over parts,
-which lowers to the same all-reduce the reference ``jnp.vdot`` emits.
+Mirrors the ``spmv_dia`` wrapper conventions: stacked ``(P, ...)`` arrays
+in the kernels' lane-dense layout (``LaneLayout.pack``), the halo'd
+``x_pad`` built from them (its static part-axis shifts lower to
+collective-permute under pjit), vmap over parts, interpret-mode fallback
+off-TPU.  The per-part block partials are finalized into **global**
+scalars with a final ``jnp.sum`` over parts, which lowers to the same
+all-reduce a reference dot emits.
 """
 from __future__ import annotations
 
@@ -14,55 +15,49 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.krylov_fused.krylov_fused import (
-    fused_axpy_precond_single, pick_block_rows, spmv_dot_single)
-# one VMEM-budget constant and one backend probe for the x_pad-resident
-# kernel families — the layout contract is shared with spmv_dia
-from repro.kernels.spmv_dia.ops import VMEM_F32_BUDGET, _on_tpu
-from repro.sparse.distributed import x_pad as make_x_pad
+from repro.kernels.krylov_fused.krylov_fused import (LaneLayout,
+                                                     axpy_precond_lanes,
+                                                     spmv_dot_lanes)
+from repro.kernels.spmv_dia.ops import _on_tpu, lane_x_pad
 
 
-@functools.partial(jax.jit, static_argnames=("offsets", "plane",
-                                             "block_rows", "accum_dtype"))
+@functools.partial(jax.jit, static_argnames=("offsets", "layout",
+                                             "accum_dtype", "difference"))
 def fused_matvec_dot(bands: jax.Array, x: jax.Array, *,
-                     offsets: tuple[int, ...], plane: int,
-                     block_rows: int = 0,
-                     accum_dtype: str | None = None
+                     offsets: tuple[int, ...], layout: LaneLayout,
+                     accum_dtype: str | None = None,
+                     difference: bool = False
                      ) -> tuple[jax.Array, jax.Array]:
-    """``(A x, x . A x)`` over stacked parts: bands (P, nb, m), x (P, m).
+    """``(A x, x . A x)`` over stacked parts in the kernel layout: bands
+    (P, nb, m_pad/128, 128), x (P, m_pad/128, 128).
 
-    One HBM pass over the bands and the halo'd vector per call;
-    ``block_rows=0`` picks the block size from the part size.
+    One HBM pass over the bands and the halo'd vector per call.
     ``accum_dtype`` (dtype name) sets the partial-reduction width for
     low-precision storage policies; ``None`` keeps the storage dtype.
+    ``difference``: as in :func:`~repro.kernels.spmv_dia.ops.spmv_dia_pallas`.
     """
-    P, nb, m = bands.shape
-    assert m + 2 * plane <= VMEM_F32_BUDGET, "x_pad exceeds the VMEM budget"
-    br = block_rows or pick_block_rows(m)
-    xp = make_x_pad(x, plane)
-    fn = functools.partial(spmv_dot_single, offsets=offsets, plane=plane,
-                           block_rows=br, interpret=not _on_tpu(),
-                           accum_dtype=accum_dtype)
-    y, part = jax.vmap(fn)(bands, xp)
+    fn = functools.partial(spmv_dot_lanes, offsets=offsets, layout=layout,
+                           accum_dtype=accum_dtype or bands.dtype.name,
+                           difference=difference, interpret=not _on_tpu())
+    y, part = jax.vmap(fn)(bands, lane_x_pad(x, layout))
     return y, jnp.sum(part)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "accum_dtype"))
 def fused_update_step(x: jax.Array, r: jax.Array, p: jax.Array,
                       Ap: jax.Array, inv_diag: jax.Array, alpha: jax.Array,
-                      *, block_rows: int = 0,
-                      accum_dtype: str | None = None):
-    """Fused axpy pair + Jacobi inverse + global ``(r'.z, r'.r')`` dots.
+                      *, block_rows: int, accum_dtype: str | None = None):
+    """Fused axpy pair + Jacobi inverse + global ``(r'.z, r'.r)`` dots.
 
-    All vectors stacked (P, m); ``alpha`` a global scalar.  Returns
-    ``(x', r', z, rz, rr)`` with the dots reduced over all parts (the two
-    scalars in ``accum_dtype`` when given, else the storage dtype).
+    All vectors stacked in the kernel layout (P, m_pad/128, 128), with
+    ``m_pad`` a multiple of ``block_rows``; ``alpha`` a global scalar.
+    Returns ``(x', r', z, rz, rr)`` with the dots reduced over all parts
+    (the two scalars in ``accum_dtype`` when given, else the storage
+    dtype).
     """
-    P, m = x.shape
-    br = block_rows or pick_block_rows(m)
-    fn = functools.partial(fused_axpy_precond_single, block_rows=br,
-                           interpret=not _on_tpu(),
-                           accum_dtype=accum_dtype)
+    fn = functools.partial(axpy_precond_lanes, block_rows=block_rows,
+                           accum_dtype=accum_dtype or x.dtype.name,
+                           interpret=not _on_tpu())
     xn, rn, z, rz, rr = jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, None))(
         x, r, p, Ap, inv_diag, alpha)
     return xn, rn, z, jnp.sum(rz), jnp.sum(rr)

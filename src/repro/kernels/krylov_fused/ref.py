@@ -23,18 +23,21 @@ def _vdot(a: jax.Array, b: jax.Array) -> jax.Array:
 
 def spmv_dot_ref(bands: jax.Array, x_pad: jax.Array, *,
                  offsets: tuple[int, ...], plane: int,
-                 accum_dtype: str | None = None):
-    """``(A p, p . A p)`` for one part."""
+                 accum_dtype: str | None = None, difference: bool = False):
+    """``(A p, p . A p)`` for one part (``difference``: the bands are in
+    ``repro.sparse.distributed.difference_form``)."""
     nb, m = bands.shape
     acc_dt = accum_dtype or bands.dtype.name
+    p = jax.lax.dynamic_slice_in_dim(x_pad, plane, m).astype(acc_dt)
     y = jnp.zeros((m,), acc_dt)
     for d, off in enumerate(offsets):
         xw = jax.lax.dynamic_slice_in_dim(x_pad, plane + off, m)
-        y = y + bands[d].astype(acc_dt) * xw.astype(acc_dt)
-    p = jax.lax.dynamic_slice_in_dim(x_pad, plane, m)
+        xw = xw.astype(acc_dt)
+        y = y + bands[d].astype(acc_dt) * (xw - p if difference and off
+                                           else xw)
     # the dot consumes the accum-width Ap (as the kernel does, before the
     # storage-dtype truncation of the vector output)
-    return y.astype(bands.dtype), _vdot(p.astype(acc_dt), y)
+    return y.astype(bands.dtype), _vdot(p, y)
 
 
 def fused_axpy_precond_ref(x: jax.Array, r: jax.Array, p: jax.Array,

@@ -7,21 +7,26 @@ TPU adaptation of the paper's GPU row-major COO SpMV: the fused FVM matrix is
 
 Layout & tiling contract (``spmv_dia.py``):
 
-* ``bands``: ``(n_bands, m)`` per part; the grid walks row blocks of
-  ``block_rows`` (default 2048; a ragged final block — any odd mesh x alpha
-  combination — is zero-padded inside ``spmv_dia_single`` and sliced off,
-  and ``pick_block_rows`` shrinks the block for sub-block parts).
-* ``x_pad``: ``(m + 2*plane,)`` resident in VMEM for the whole grid
-  (``ops.py`` asserts the fp32 budget, ``VMEM_F32_BUDGET``); band tiles
-  stream through VMEM and double-buffer via the Pallas pipeline.
+* every part vector is lane-dense ``(rows / 128, 128)``; bands
+  ``(n_bands, rows / 128, 128)`` per part, walked in ``block_rows`` row
+  blocks of whole ``(sublanes, 128)`` tiles (``pick_block_rows``); the
+  rows are zero-padded to whole blocks (``LaneLayout``, from
+  ``lane_layout``).
+* ``x_pad``: the part's row 0 at a tile-aligned ``lead``; resident in
+  VMEM for the whole grid, with the scoped VMEM sized by
+  ``vmem_limit_bytes``; band windows are aligned dynamic loads plus
+  static sublane slices and lane rolls.
 * halo planes are zero at physical boundaries, matching the zero interface
   coefficients there, so no masking is needed.
+* ``difference=True``: the offset-0 band holds the row sums and the other
+  bands act on ``x_j - x_i`` (the low-precision solves' form).
+* compiled (non-interpret) calls refuse 8-byte dtypes
+  (``check_kernel_dtypes``).
 
 Entry points: :func:`~repro.kernels.spmv_dia.ops.spmv_dia_pallas` (stacked
-parts ``(P, nb, m)``, falls back to interpret mode off-TPU) and
-``spmv_dia_single`` (one part).  ``ref.py`` holds the pure-jnp oracle
-``spmv_dia_ref`` — the contract is bit-exact agreement per dtype, enforced by
-``tests/test_kernels.py`` and timed by ``benchmarks/kernels_bench.py``
-(see docs/kernels.md).
+parts already laid out, ``(P, nb, rows/128, 128)``; interpret mode
+off-TPU) and ``spmv_dia_single`` (one flat part, laid out per call).
+``ref.py`` holds the pure-jnp oracle ``spmv_dia_ref``, enforced by
+``tests/test_krylov_fused.py`` (see docs/kernels.md).
 """
 from repro.kernels.spmv_dia.ops import spmv_dia_pallas  # noqa: F401

@@ -9,35 +9,43 @@ import functools
 
 import jax
 
-from repro.kernels.spmv_dia.spmv_dia import spmv_dia_single, DEFAULT_BLOCK_ROWS
-from repro.sparse.distributed import x_pad as make_x_pad
-
-VMEM_F32_BUDGET = 3_500_000  # floats of x_pad we allow resident in VMEM
+from repro.kernels.spmv_dia.spmv_dia import (LaneLayout, band_call,
+                                             check_kernel_dtypes)
+from repro.sparse.distributed import x_pad_lanes
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("offsets", "plane", "block_rows",
-                                             "accum_dtype"))
-def spmv_dia_pallas(bands: jax.Array, x: jax.Array, *,
-                    offsets: tuple[int, ...], plane: int,
-                    block_rows: int = DEFAULT_BLOCK_ROWS,
-                    accum_dtype: str | None = None) -> jax.Array:
-    """Stacked SpMV: bands (P, nb, m), x (P, m) → y (P, m).
+def lane_x_pad(x: jax.Array, layout: LaneLayout) -> jax.Array:
+    """The halo'd ``x_pad`` of lane-dense stacked parts (the shifts across
+    the part axis lower to collective-permute under pjit)."""
+    return x_pad_lanes(x, m=layout.m, plane=layout.plane, lead=layout.lead,
+                       x_len=layout.x_len)
 
-    Builds the halo'd x_pad (the shifts across the part axis lower to
-    collective-permute under pjit), then vmaps the single-part Pallas
-    kernel over parts.  Ragged row counts are handled inside
-    ``spmv_dia_single`` (zero-padded tail block, sliced off).
+
+@functools.partial(jax.jit, static_argnames=("offsets", "layout",
+                                             "accum_dtype", "difference"))
+def spmv_dia_pallas(bands: jax.Array, x: jax.Array, *,
+                    offsets: tuple[int, ...], layout: LaneLayout,
+                    accum_dtype: str | None = None,
+                    difference: bool = False) -> jax.Array:
+    """Stacked SpMV in the kernel layout: bands (P, nb, m_pad/128, 128),
+    x (P, m_pad/128, 128) → y (P, m_pad/128, 128) (``layout.pack``).
+
+    Builds the halo'd x_pad, then vmaps the band kernel over parts.
     ``accum_dtype`` (dtype name) widens the in-kernel row accumulator for
     low-precision bands; ``None`` accumulates in the storage dtype.
+    ``difference``: the bands are in
+    :func:`~repro.sparse.distributed.difference_form`.
     """
-    P, nb, m = bands.shape
-    assert m + 2 * plane <= VMEM_F32_BUDGET, "x_pad exceeds the VMEM budget"
-    xp = make_x_pad(x, plane)  # (P, m + 2*plane)
-    fn = functools.partial(spmv_dia_single, offsets=offsets, plane=plane,
-                           block_rows=block_rows, interpret=not _on_tpu(),
-                           accum_dtype=accum_dtype)
-    return jax.vmap(fn)(bands, xp)
+    accum_dtype = accum_dtype or bands.dtype.name
+    interpret = not _on_tpu()
+    check_kernel_dtypes(interpret, bands.dtype, accum_dtype)
+    fn = functools.partial(band_call, offsets=offsets, lead=layout.lead,
+                           block_rows=layout.block_rows,
+                           accum_dtype=accum_dtype, with_dot=False,
+                           difference=difference, interpret=interpret)
+    (y,) = jax.vmap(fn)(bands, lane_x_pad(x, layout))
+    return y

@@ -21,8 +21,8 @@ import time
 
 from repro.core.controller import (ControllerConfig, PlanCache,
                                    RepartitionController)
-from repro.core.cost_model import CostModel, TPU_V5E
-from repro.env import enable_x64
+from repro.core.cost_model import CostModel, hardware_for
+from repro.env import enable_compile_cache, enable_x64
 from repro.fvm.cases import case_names, get_case
 from repro.fvm.mesh import CavityMesh
 from repro.fvm.piso import SOLVERS, make_solver
@@ -66,6 +66,19 @@ def build_parser() -> argparse.ArgumentParser:
                          "dots Pallas kernels; reference = the plain jnp op "
                          "sequence; auto picks fused once a part fills a "
                          "kernel row block")
+    ap.add_argument("--precision", default="f64",
+                    choices=["f64", "f32_ir", "bf16_ir"],
+                    help="Krylov precision policy (repro.solvers.precision): "
+                         "f64 solves in double precision; f32_ir/bf16_ir "
+                         "run the inner sweeps at f32/bf16 storage inside "
+                         "an f64 iterative-refinement loop (the Pallas TPU "
+                         "kernels take no f64, so on TPU the fused backend "
+                         "needs one of these)")
+    ap.add_argument("--p-tol", type=float, default=1e-8,
+                    help="relative tolerance of the pressure CG solves")
+    ap.add_argument("--mom-tol", type=float, default=1e-7,
+                    help="relative tolerance of the momentum BiCGStab "
+                         "solves")
     ap.add_argument("--pipeline", default="auto",
                     choices=["auto", "on", "off"],
                     help="software-pipelined stepping (PipelinedExecutor): "
@@ -97,13 +110,40 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def solve_health(stats, j=None) -> str:
+    """``ok``, ``CAPPED``, ``DIVERGED`` or ``UNCONVERGED``: the Krylov
+    health flags of one step's ``StepStats`` (step ``j`` of a window)."""
+    def flag(name):
+        v = getattr(stats, name)
+        return bool(v if j is None else v[j])
+
+    if flag("diverged"):
+        return "DIVERGED"
+    if flag("hit_cap"):
+        return "CAPPED"
+    return "ok" if flag("converged") else "UNCONVERGED"
+
+
+def fail_on_unhealthy(bad: list[str], solver) -> None:
+    """Exit non-zero, naming the steps, when any Krylov solve failed: a
+    capped or diverged solve must never pass for a result."""
+    if bad:
+        raise SystemExit(
+            f"FAILED: Krylov solves failed at {', '.join(bad)} "
+            f"(p_maxiter={solver.p_maxiter}, "
+            f"mom_maxiter={solver.mom_maxiter})")
+
+
 def run_steady(args, mesh, alpha, nu) -> None:
     """Steady program: iterate to the program's convergence predicate."""
     solver = make_solver(args.program, mesh, alpha=alpha, nu=nu,
                          case=args.case, update_schedule=args.schedule,
                          solve_mode=args.solve_mode,
                          solver_backend=args.solver_backend,
-                         pipeline=args.pipeline)
+                         pipeline=args.pipeline,
+                         precision=args.precision, p_tol=args.p_tol,
+                         mom_tol=args.mom_tol)
+    print(solver.describe_backends())
     dt = args.co * mesh.h  # ignored by steady assembly; kept for the ABI
     cap = args.max_outer or None
     t0 = time.time()
@@ -119,11 +159,15 @@ def run_steady(args, mesh, alpha, nu) -> None:
     print(f"  continuity={cont:.2e} (tol {solver.tol_continuity:.0e}) "
           f"u_delta={u_delta:.2e} (tol {solver.tol_u:.0e}) "
           f"mom_iters={int(stats.mom_iters)} "
-          f"p_iters={[int(i) for i in stats.p_iters]}")
+          f"p_iters={[int(i) for i in stats.p_iters]} "
+          f"solves={solve_health(stats)}")
     print(f"  ({mesh.n_cells_global} cells, alpha={solver.alpha}, "
           f"relax_u={solver.relax_u}, relax_p={solver.relax_p}, "
           f"solve_mode={args.solve_mode}, "
           f"solver_backend={args.solver_backend})")
+    health = solve_health(stats)
+    fail_on_unhealthy([] if health == "ok" else [f"outer {n_outer}: {health}"],
+                      solver)
 
 
 def run_transient(args, mesh, alpha, nu, cm) -> None:
@@ -152,7 +196,10 @@ def run_transient(args, mesh, alpha, nu, cm) -> None:
                              case=args.case, update_schedule=args.schedule,
                              plan_cache=cache, solve_mode=args.solve_mode,
                              solver_backend=args.solver_backend,
-                             pipeline=args.pipeline)
+                             pipeline=args.pipeline,
+                             precision=args.precision, p_tol=args.p_tol,
+                             mom_tol=args.mom_tol)
+        print(solver.describe_backends())
         print(f"controller start: alpha={ctl.alpha} "
               f"solve_mode={args.solve_mode} "
               f"solver_backend={args.solver_backend} "
@@ -161,6 +208,7 @@ def run_transient(args, mesh, alpha, nu, cm) -> None:
         state = solver.initial_state()
         t0 = time.time()
         step = 0
+        bad = []
         # same cadence driver as SimulationEngine.step_session: sample the
         # instrumented walk on the anchored grid, scan-roll the stretches
         for is_sample, chunk in roll_schedule(0, args.steps,
@@ -169,6 +217,8 @@ def run_transient(args, mesh, alpha, nu, cm) -> None:
             if is_sample:
                 # instrumented sample: per-phase timers feed the controller
                 state, stats, sample = solver.timed_step(state, dt)
+                if solve_health(stats) != "ok":
+                    bad.append(f"step {step}: {solve_health(stats)}")
                 new_alpha = ctl.step(sample)
                 if new_alpha != solver.alpha:
                     print(f"step {step}: controller switch alpha "
@@ -183,6 +233,9 @@ def run_transient(args, mesh, alpha, nu, cm) -> None:
             else:
                 # fused scan-rolled stretch: ONE XLA dispatch
                 state, window = solver.run_steps(state, dt, chunk)
+                bad += [f"step {step + j}: {solve_health(window, j)}"
+                        for j in range(chunk)
+                        if solve_health(window, j) != "ok"]
                 print(f"steps {step}..{step + chunk - 1}: "
                       f"alpha={solver.alpha} rolled x{chunk} "
                       f"p_iters={[int(i) for i in window.p_iters[-1]]} "
@@ -194,6 +247,7 @@ def run_transient(args, mesh, alpha, nu, cm) -> None:
               f"{len(s['switches'])} switch(es), "
               f"plan cache {s['cache']['hits']} hits / "
               f"{s['cache']['misses']} misses")
+        fail_on_unhealthy(bad, solver)
         return
 
     if alpha is None:
@@ -205,19 +259,27 @@ def run_transient(args, mesh, alpha, nu, cm) -> None:
                          case=args.case, update_schedule=args.schedule,
                          solve_mode=args.solve_mode,
                          solver_backend=args.solver_backend,
-                         pipeline=args.pipeline)
+                         pipeline=args.pipeline,
+                         precision=args.precision, p_tol=args.p_tol,
+                         mom_tol=args.mom_tol)
+    print(solver.describe_backends())
     state = solver.initial_state()
     t0 = time.time()
     scan = max(args.scan_steps, 1)
     step = 0
+    bad = []
     # every=None: no sampling — pure scan-rolled windows of <= scan steps
     for _sample, chunk in roll_schedule(0, args.steps, None, cap=scan):
         # each window is ONE XLA dispatch; stats come back per-step stacked
         state, stats = solver.run_steps(state, dt, chunk)
         for j in range(chunk):
+            health = solve_health(stats, j)
             print(f"step {step + j}: mom_iters={int(stats.mom_iters[j])} "
                   f"p_iters={[int(i) for i in stats.p_iters[j]]} "
-                  f"continuity={float(stats.continuity_err[j]):.2e}")
+                  f"continuity={float(stats.continuity_err[j]):.2e} "
+                  f"solves={health}")
+            if health != "ok":
+                bad.append(f"step {step + j}: {health}")
         step += chunk
     print(f"{args.steps} steps in {time.time() - t0:.2f}s "
           f"({mesh.n_cells_global} cells, alpha={alpha}, "
@@ -225,6 +287,7 @@ def run_transient(args, mesh, alpha, nu, cm) -> None:
           f"solver_backend={args.solver_backend}, "
           f"pipeline={args.pipeline} (resolved {solver.pipelined}), "
           f"scan_steps={scan})")
+    fail_on_unhealthy(bad, solver)
 
 
 def main(argv=None):
@@ -238,6 +301,9 @@ def main(argv=None):
 
         configure_platform()
     enable_x64()
+    enable_compile_cache()
+    import jax
+
     # resolve "auto" at the fine part size — the smallest solve part any
     # alpha produces, so the cost model's fused bytes/iter prior flips
     # only when every candidate alpha runs the fused kernels (larger
@@ -246,9 +312,11 @@ def main(argv=None):
     from repro.solvers.ops import resolve_backend
 
     eff_backend = resolve_backend(args.solver_backend,
-                                  args.n ** 3 // args.parts)
-    cm = CostModel(TPU_V5E, n_dofs=args.n ** 3,
-                   fused_solver=eff_backend == "fused")
+                                  args.n ** 3 // args.parts,
+                                  precision=args.precision)
+    cm = CostModel(hardware_for(jax.devices()[0]), n_dofs=args.n ** 3,
+                   fused_solver=eff_backend == "fused"
+                   ).with_precision(args.precision)
     alpha = args.alpha
     if alpha == 0 or args.adaptive:
         alpha = None  # let the controller/cost model pick

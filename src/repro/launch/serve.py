@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_config, get_smoke_config
-from repro.env import enable_x64
+from repro.env import enable_compile_cache, enable_x64
 from repro.models import lm
 from repro.serving.engine import generate
 
@@ -355,6 +355,7 @@ def main():
         from repro.env import configure_platform
 
         configure_platform()
+    enable_compile_cache()
 
     if args.sessions > 0 or args.resume:
         if (args.supervise or args.resume or args.chaos is not None

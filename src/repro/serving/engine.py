@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from repro.core.controller import (ControllerConfig, PlanCache,
                                    RepartitionController)
-from repro.core.cost_model import CostModel, TPU_V5E
+from repro.core.cost_model import CostModel, hardware_for
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -259,7 +259,8 @@ class SimulationEngine:
             mesh = PaddedCavityMesh.pad(mesh, pad_to_class)
         # cost honesty for padded meshes: ghost slabs carry no dofs
         n_dofs = getattr(mesh, "n_cells_active", mesh.n_cells_global)
-        model = model or CostModel(TPU_V5E, n_dofs=n_dofs)
+        model = model or CostModel(hardware_for(jax.devices()[0]),
+                                   n_dofs=n_dofs)
         # fixed_fine feasibility already restricts alphas to divisors of
         # n_cpu = mesh.n_parts, i.e. to plans realizable on the mesh
         # resolve the pipeline knob against the program spec up front so

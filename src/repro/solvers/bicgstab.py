@@ -16,7 +16,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.solvers.ops import SolverOps, reference_ops
+from repro.solvers.ops import SolverOps, reference_ops, vdot
 
 __all__ = ["bicgstab", "BiCGStabResult"]
 
@@ -42,8 +42,11 @@ def _bicgstab_sweep(ops: SolverOps, b, x0, threshold_sq, maxiter):
     Returns ``(x, rr, k)``; the scalar carries (rho/alpha/omega/rr) live
     at the accum dtype of the bundle's dots, vector updates cast the
     scalars down per use — every cast is a no-op on the f64 policy, so
-    this is bit-identical to the pre-policy solver body there.
+    there it is the plain solver body.
+    The sweep runs in the bundle's vector layout (``ops.pack`` in,
+    ``ops.unpack`` out).
     """
+    b, x0 = ops.pack(b), ops.pack(x0)
     r0 = b - ops.matvec(x0)
     rhat = r0  # shadow residual
     (rr0,) = ops.dots((r0, r0))
@@ -83,7 +86,7 @@ def _bicgstab_sweep(ops: SolverOps, b, x0, threshold_sq, maxiter):
     init = (x0, r0, jnp.zeros_like(b), jnp.zeros_like(b), one, one, one,
             rr0, jnp.array(0, jnp.int32), jnp.array(False))
     x, r, *_, rr, k, _ = jax.lax.while_loop(cond, body, init)
-    return x, rr, k
+    return ops.unpack(x), rr, k
 
 
 def _bicgstab_refined(ops: SolverOps, b, x0, *, tol, atol,
@@ -93,16 +96,13 @@ def _bicgstab_refined(ops: SolverOps, b, x0, *, tol, atol,
     A_hi = ops.matvec_hi if ops.matvec_hi is not None else ops.matvec
     lo = pol.storage_dtype
 
-    def vdot_hi(u, v):
-        return jnp.vdot(u, v, precision=jax.lax.Precision.HIGHEST)
-
-    bb = vdot_hi(b, b)
+    bb = vdot(b, b)
     threshold_sq = jnp.maximum(tol * jnp.sqrt(bb), atol) ** 2
     inner_tol_sq = pol.inner_tol ** 2
 
     def residual(x):
         r = b - A_hi(x)
-        return r, vdot_hi(r, r)
+        return r, vdot(r, r)
 
     r0, rr0 = residual(x0)
 
@@ -111,13 +111,15 @@ def _bicgstab_refined(ops: SolverOps, b, x0, *, tol, atol,
         return (rr > threshold_sq) & (k_out < pol.max_outer)
 
     def body(state):
-        x, r, _, k_out, inner_total, inner_capped = state
-        r_lo = r.astype(lo)
+        x, r, rr, k_out, inner_total, inner_capped = state
+        # unit-norm correction rhs, as in repro.solvers.cg._cg_refined
+        scale = jnp.sqrt(rr)
+        r_lo = (r / scale).astype(lo)
         (rr_lo,) = ops.dots((r_lo, r_lo))
         thr_lo = inner_tol_sq * rr_lo
         d, _, k_in = _bicgstab_sweep(ops, r_lo, jnp.zeros_like(r_lo),
                                      thr_lo, maxiter)
-        x = x + d.astype(b.dtype)
+        x = x + scale * d.astype(b.dtype)
         r, rr = residual(x)
         return (x, r, rr, k_out + 1, inner_total + k_in,
                 inner_capped | (k_in >= maxiter))

@@ -67,8 +67,8 @@ class PrecisionPolicy:
         return jnp.dtype(self.accum)
 
 
-# The do-nothing policy: everything f64, no outer loop — the pre-policy
-# solver behaviour, bit-identical by construction (all casts are no-ops).
+# The do-nothing policy: everything f64, no outer loop — the plain
+# solver (all casts are no-ops).
 F64 = PrecisionPolicy(name="f64", storage="float64", accum="float64",
                       storage_itemsize=8, accum_itemsize=8,
                       refine=False, inner_tol=0.0, max_outer=0)

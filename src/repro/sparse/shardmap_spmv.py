@@ -22,13 +22,10 @@ to run the collective concurrently with the interior SpMV — the classic
 halo-overlap schedule of GPU-resident PISO solvers (Oliani et al.
 arXiv:2403.07882, Tomczak et al. arXiv:1207.1571).
 
-**Local compute.**  On TPU (and always under the fused backend) the
-per-shard banded apply runs through the ``spmv_dia`` Pallas kernel — one
-HBM pass over the local bands, the same kernel the stacked path uses —
-instead of an unrolled jnp shift loop; off-TPU the reference path keeps
-the jnp loop, because the kernel would execute through the Pallas
-*interpreter* inside the CG ``while_loop`` (a Python-level emulation,
-~50x wall overhead on host devices, measured via fig7_full_mesh).
+**Local compute.**  The full-mesh solve is f64-only, and the Pallas TPU
+kernels take no f64 (an f64 custom call is unimplemented on TPU), so the
+per-shard banded apply is the jnp shift loop (``spmv_dia_ref``) on every
+backend.
 
 Requires m_loc >= plane (one halo plane per side), i.e. each device holds
 at least one z-plane of the fused block — true for all production configs.
@@ -51,36 +48,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.comm import ASSEMBLE_AXIS, SOLVE_AXIS
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _local_dia(b, x_pad, *, offsets, plane, m_loc, use_kernel):
-    """Per-shard banded apply: the spmv_dia Pallas kernel (one HBM pass) or
-    the jnp shift loop.
-
-    ``use_kernel=None`` resolves to "kernel on TPU, jnp off-TPU": the
-    interpret-mode kernel is a Python-level emulation whose per-grid-step
-    overhead lands inside the CG while_loop — fine for parity tests (the
-    fused backend forces it), ruinous for the CPU-device wall times the
-    reference path is benchmarked at.
-    """
-    if use_kernel is None:
-        use_kernel = _on_tpu()
-    if use_kernel:
-        from repro.kernels.spmv_dia.spmv_dia import (pick_block_rows,
-                                                     spmv_dia_single)
-
-        return spmv_dia_single(b, x_pad, offsets=offsets, plane=plane,
-                               block_rows=pick_block_rows(m_loc),
-                               interpret=not _on_tpu())
-    from repro.kernels.spmv_dia.ref import spmv_dia_ref
-
-    return spmv_dia_ref(b, x_pad, offsets=offsets, plane=plane)
+from repro.kernels.spmv_dia.ref import spmv_dia_ref
+from repro.solvers.ops import vdot
 
 
 def _boundary_add(y, b, down, up, *, offsets, plane, m_loc):
@@ -112,16 +82,13 @@ def _boundary_add(y, b, down, up, *, offsets, plane, m_loc):
 
 def make_spmv_full_mesh(mesh: Mesh, *, offsets: tuple[int, ...], plane: int,
                         n_coarse: int, alpha: int, m_coarse: int,
-                        with_dot: bool = False,
-                        use_kernel: bool | None = None):
+                        with_dot: bool = False):
     """Returns A(bands, x) with rows sharded over (solve, assemble).
 
     bands: (n_c, nb, m_c) global; x: (n_c, m_c) global.  Out like x.
     With ``with_dot=True`` the apply also returns the global ``x . A x``
     (per-shard partial computed in the same pass, ``psum`` over both mesh
-    axes) — the fused backend's ``matvec_dot``.  ``use_kernel`` routes the
-    local compute through the spmv_dia Pallas kernel (default: on TPU; the
-    fused backend forces it everywhere, see :func:`_local_dia`).
+    axes) — the fused backend's ``matvec_dot``.
     """
     m_loc = m_coarse // alpha
     assert m_loc >= plane, (m_loc, plane)
@@ -134,7 +101,7 @@ def make_spmv_full_mesh(mesh: Mesh, *, offsets: tuple[int, ...], plane: int,
         else P(SOLVE_AXIS, ASSEMBLE_AXIS)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(SOLVE_AXIS, None, ASSEMBLE_AXIS),
                   P(SOLVE_AXIS, ASSEMBLE_AXIS)),
         out_specs=out_specs, check_vma=False)
@@ -153,14 +120,13 @@ def make_spmv_full_mesh(mesh: Mesh, *, offsets: tuple[int, ...], plane: int,
         # zero halos => every row against the locally held vector only
         xp_loc = jnp.concatenate([jnp.zeros((plane,), xv.dtype), xv,
                                   jnp.zeros((plane,), xv.dtype)])
-        y = _local_dia(b_loc[0], xp_loc, offsets=offsets, plane=plane,
-                       m_loc=m_loc, use_kernel=use_kernel)
+        y = spmv_dia_ref(b_loc[0], xp_loc, offsets=offsets, plane=plane)
         # (3) boundary-plane band contributions from the received halos
         y = _boundary_add(y, b_loc[0], down, up, offsets=offsets,
                           plane=plane, m_loc=m_loc)
         if not with_dot:
             return y[None, :]
-        part = jnp.vdot(xv, y, precision=jax.lax.Precision.HIGHEST)
+        part = vdot(xv, y)
         return y[None, :], jax.lax.psum(part, axes)
 
     return spmv
@@ -176,7 +142,7 @@ def make_jacobi_full_mesh(mesh: Mesh, diag: jax.Array):
     """
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(SOLVE_AXIS, ASSEMBLE_AXIS),
                   P(SOLVE_AXIS, ASSEMBLE_AXIS)),
         out_specs=P(SOLVE_AXIS, ASSEMBLE_AXIS), check_vma=False)
@@ -198,17 +164,16 @@ def make_fused_step_full_mesh(mesh: Mesh, diag: jax.Array):
     sharded = P(SOLVE_AXIS, ASSEMBLE_AXIS)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(sharded,) * 5 + (P(),),
         out_specs=(sharded, sharded, sharded, P(), P()),
         check_vma=False)
     def step(x_loc, r_loc, p_loc, ap_loc, d_loc, alpha):
-        hi = jax.lax.Precision.HIGHEST
         xn = x_loc + alpha * p_loc
         rn = r_loc - alpha * ap_loc
         z = rn / d_loc
-        rz = jax.lax.psum(jnp.vdot(rn, z, precision=hi), axes)
-        rr = jax.lax.psum(jnp.vdot(rn, rn, precision=hi), axes)
+        rz = jax.lax.psum(vdot(rn, z), axes)
+        rr = jax.lax.psum(vdot(rn, rn), axes)
         return xn, rn, z, rz, rr
 
     return lambda x, r, p, Ap, alpha: step(x, r, p, Ap, diag, alpha)
@@ -228,7 +193,7 @@ def make_fused_ops_full_mesh(mesh: Mesh, bands: jax.Array, diag: jax.Array,
     from repro.solvers.ops import SolverOps, _reference_dots
 
     kw = dict(offsets=offsets, plane=plane, n_coarse=n_coarse, alpha=alpha,
-              m_coarse=m_coarse, use_kernel=True)
+              m_coarse=m_coarse)
     plain = make_spmv_full_mesh(mesh, **kw)
     fused = make_spmv_full_mesh(mesh, with_dot=True, **kw)
     precond = make_jacobi_full_mesh(mesh, diag)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -71,7 +70,7 @@ def pipelined_forward(cfg: ModelConfig, params, tokens, *, mesh: Mesh,
         return out
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P("pod"), P(None, "data", None, None)),
         out_specs=P(None, "data", None, None), check_vma=False)
     def run(pp, micro):

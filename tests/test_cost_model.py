@@ -131,3 +131,20 @@ def test_phase_breakdown_overlapped_provenance():
     assert po.total == ph.total == pytest.approx(10.0)
     assert PhaseBreakdown.TIME_FIELDS == ("assembly", "update", "halo",
                                           "solve")
+
+
+def test_hardware_for_prices_by_device_kind():
+    """TPU devices are priced from the device_kind table (an unknown kind
+    is an error, not a default); other platforms keep the v5e prior."""
+    from types import SimpleNamespace
+
+    from repro.core.cost_model import TPU_SPECS, hardware_for
+
+    def dev(platform, kind):
+        return SimpleNamespace(platform=platform, device_kind=kind)
+
+    assert hardware_for(dev("tpu", "TPU v5 lite")) is TPU_V5E
+    assert set(TPU_SPECS.values()) == {TPU_V5E}
+    assert hardware_for(dev("cpu", "cpu")) is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v4"):
+        hardware_for(dev("tpu", "TPU v4"))

@@ -99,3 +99,35 @@ def test_platform_pin_is_soft():
     assert out.returncode == 0, out.stderr
     # explicit user env wins over the pin
     assert out.stdout.strip() == "cpu"
+
+
+def test_compile_cache_honours_env_var(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins as it stands; no other directory is
+    set in code."""
+    out = run_py(
+        "import jax\n"
+        "from repro.env import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n",
+        JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [str(tmp_path), str(tmp_path)]
+
+
+def test_compile_cache_defaults_to_checkout_dir():
+    """Without the env var the cache sits at a fixed ``.jax_cache`` of the
+    checkout — never a temporary, per-process or per-run path."""
+    from repro.env import COMPILE_CACHE_DIR
+
+    out = run_py(
+        "import os\n"
+        "os.environ.pop('JAX_COMPILATION_CACHE_DIR', None)\n"
+        "import jax\n"
+        "from repro.env import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n",
+        JAX_PLATFORMS="cpu")
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [COMPILE_CACHE_DIR, COMPILE_CACHE_DIR]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert COMPILE_CACHE_DIR == os.path.join(root, ".jax_cache")

@@ -13,7 +13,7 @@ from repro.fvm.assembly import CavityAssembly
 from repro.fvm.mesh import CavityMesh
 from repro.kernels.spmv_dia.ops import spmv_dia_pallas
 from repro.kernels.spmv_dia.ref import spmv_dia_ref
-from repro.kernels.spmv_dia.spmv_dia import spmv_dia_single
+from repro.kernels.spmv_dia.spmv_dia import lane_layout, spmv_dia_single
 from repro.kernels.coef_update.ops import coef_update_pallas
 from repro.kernels.coef_update.ref import coef_update_ref
 from repro.kernels.coef_update.coef_update import coef_update_single
@@ -53,8 +53,9 @@ def test_spmv_dia_pallas_matches_distributed_spmv():
     x = jnp.asarray(rng.standard_normal((n_c, plan.m_coarse)))
     offsets = tuple(int(o) for o in plan.dia_offsets)
     y_ref = spmv_dia(bands, x, offsets=offsets, plane=plan.plane)
-    y_pal = spmv_dia_pallas(bands, x, offsets=offsets, plane=plan.plane,
-                            block_rows=64)
+    lay = lane_layout(plan.m_coarse, plan.plane, 64, bands.dtype)
+    y_pal = lay.unpack(spmv_dia_pallas(lay.pack(bands), lay.pack(x),
+                                       offsets=offsets, layout=lay))
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=1e-12)
 

@@ -17,13 +17,16 @@ from repro.kernels.krylov_fused.ref import (fused_axpy_precond_ref,
                                             spmv_dot_ref)
 from repro.kernels.spmv_dia.ops import spmv_dia_pallas
 from repro.kernels.spmv_dia.ref import spmv_dia_ref
-from repro.kernels.spmv_dia.spmv_dia import spmv_dia_single
+from repro.kernels.spmv_dia.spmv_dia import (DEFAULT_BLOCK_ROWS,
+                                             lane_layout, spmv_dia_single,
+                                             sublanes)
 from repro.solvers.bicgstab import bicgstab
 from repro.solvers.cg import cg
 from repro.solvers.jacobi import jacobi_preconditioner
 from repro.solvers.ops import (FUSED_MIN_ROWS, fused_stacked_ops,
                                reference_ops, resolve_backend)
-from repro.sparse.distributed import spmv_dia
+from repro.sparse.distributed import (difference_form, spmv_dia, x_pad,
+                                      x_pad_lanes)
 
 from helpers import global_dense
 from test_solvers import laplacian_buffers
@@ -94,17 +97,88 @@ def test_spmv_dia_pallas_stacked_odd_parts():
     bands = jnp.asarray(rng.standard_normal((P, 7, m)))
     x = jnp.asarray(rng.standard_normal((P, m)))
     y_ref = spmv_dia(bands, x, offsets=offsets, plane=plane)
-    y = spmv_dia_pallas(bands, x, offsets=offsets, plane=plane,
-                        block_rows=64)
+    lay = lane_layout(m, plane, 64, bands.dtype)
+    y = lay.unpack(spmv_dia_pallas(lay.pack(bands), lay.pack(x),
+                                   offsets=offsets, layout=lay))
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-12,
                                atol=1e-12)
 
 
+@pytest.mark.parametrize("m,plane", [(243, 9), (4096, 256), (1000, 100)])
+def test_x_pad_lanes_matches_flat_x_pad(m, plane):
+    """The lane-dense x_pad built from packed parts equals the flat
+    [down-halo | x | up-halo] laid out, for any lane remainder of m and
+    plane; rows of x past m never leak in."""
+    P = 3
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.standard_normal((P, m)))
+    lay = lane_layout(m, plane, 1024, jnp.float32)
+    packed = lay.pack(x)
+    junk = packed.reshape(P, -1).at[:, m:].set(7.0).reshape(packed.shape)
+    got = x_pad_lanes(junk, m=m, plane=plane, lead=lay.lead,
+                      x_len=lay.x_len)
+    want = jax.vmap(lay.pack_x_pad)(x_pad(x, plane))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_difference_form_is_the_same_operator():
+    """difference_form moves the diagonal into row sums; the SpMV in
+    difference form is the same operator (f64 round-off), and a constant
+    vector maps exactly to the row sums."""
+    plane, nx, m, P = 9, 3, 243, 3
+    offsets = (-plane, -nx, -1, 0, 1, nx, plane)
+    rng = np.random.default_rng(5)
+    bands = jnp.asarray(rng.standard_normal((P, 7, m)))
+    x = jnp.asarray(rng.standard_normal((P, m)))
+    bd = difference_form(bands, offsets)
+    np.testing.assert_allclose(
+        np.asarray(spmv_dia(bd, x, offsets=offsets, plane=plane,
+                            difference=True)),
+        np.asarray(spmv_dia(bands, x, offsets=offsets, plane=plane)),
+        rtol=1e-12, atol=1e-12)
+    lo = bd.astype(jnp.float32)
+    ones = jnp.ones((P, m), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(spmv_dia(lo, ones, offsets=offsets, plane=plane,
+                            difference=True))[1:-1],
+        np.asarray(lo[1:-1, 3]))  # interior parts: every neighbour is 1
+
+
+@pytest.mark.parametrize("kernel", ["spmv_dia_single", "spmv_dot_single"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_band_kernels_difference_form_vs_ref(kernel, dtype, tol):
+    m, plane, block, nx = 777, 16, 256, 4
+    offsets = (-plane, -nx, -1, 0, 1, nx, plane)
+    rng = np.random.default_rng(13)
+    bands = jnp.asarray(rng.standard_normal((7, m)), dtype)
+    xp = jnp.asarray(rng.standard_normal(m + 2 * plane), dtype)
+    kw = dict(offsets=offsets, plane=plane, difference=True)
+    y_r, d_r = spmv_dot_ref(bands, xp, accum_dtype="float32", **kw)
+    if kernel == "spmv_dia_single":
+        y_k = spmv_dia_single(bands, xp, block_rows=block, interpret=True,
+                              accum_dtype="float32", **kw)
+    else:
+        y_k, d_k = spmv_dot_single(bands, xp, block_rows=block,
+                                   interpret=True, accum_dtype="float32",
+                                   **kw)
+        np.testing.assert_allclose(float(d_k), float(d_r), rtol=10 * tol,
+                                   atol=10 * tol)
+    np.testing.assert_allclose(np.asarray(y_k, np.float32),
+                               np.asarray(y_r, np.float32), rtol=tol,
+                               atol=tol)
+
+
 def test_pick_block_rows():
-    assert pick_block_rows(1 << 20) == 2048
-    assert pick_block_rows(2048) == 2048
-    assert pick_block_rows(200) == 256   # rounded to the 128-lane width
-    assert pick_block_rows(64) == 128
+    """Row blocks are whole (sublanes, 128) tiles of the lane-dense
+    layout: 8 sublanes for 4-byte dtypes, 16 for bf16's packed ones."""
+    assert pick_block_rows(1 << 20) == DEFAULT_BLOCK_ROWS
+    assert pick_block_rows(DEFAULT_BLOCK_ROWS) == DEFAULT_BLOCK_ROWS
+    assert pick_block_rows(200) == 1024    # one (8, 128) f32 tile
+    assert pick_block_rows(1025) == 2048
+    assert pick_block_rows(200, jnp.bfloat16) == 2048  # one (16, 128) tile
+    for dt in (jnp.float32, jnp.bfloat16):
+        assert pick_block_rows(1_852_200, dt) % (sublanes(dt) * 128) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -174,14 +248,19 @@ def test_fused_matvec_dot_and_update_step_global_reductions():
     bands = jnp.asarray(rng.standard_normal((P, 7, m)))
     x = jnp.asarray(rng.standard_normal((P, m)))
     y_ref = spmv_dia(bands, x, offsets=offsets, plane=plane)
-    y, d = fused_matvec_dot(bands, x, offsets=offsets, plane=plane)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-12)
+    lay = lane_layout(m, plane, pick_block_rows(m), bands.dtype)
+    y, d = fused_matvec_dot(lay.pack(bands), lay.pack(x), offsets=offsets,
+                            layout=lay)
+    np.testing.assert_allclose(np.asarray(lay.unpack(y)), np.asarray(y_ref),
+                               rtol=1e-12)
     np.testing.assert_allclose(float(d), float(jnp.vdot(x, y_ref)),
                                rtol=1e-12)
     inv = jnp.asarray(1.0 / (1.0 + np.abs(rng.standard_normal((P, m)))))
     alpha = jnp.asarray(0.41)
-    xn, rn, z, rz, rr = fused_update_step(x, x * 0.3, x * 0.2, y_ref, inv,
-                                          alpha)
+    xn, rn, z, rz, rr = fused_update_step(
+        *(lay.pack(v) for v in (x, x * 0.3, x * 0.2, y_ref, inv)), alpha,
+        block_rows=lay.block_rows)
+    rn = lay.unpack(rn)
     rn_ref = x * 0.3 - alpha * y_ref
     np.testing.assert_allclose(np.asarray(rn), np.asarray(rn_ref), rtol=1e-12)
     np.testing.assert_allclose(float(rr), float(jnp.vdot(rn_ref, rn_ref)),
@@ -202,12 +281,12 @@ def test_fused_ops_zero_diag_safe_reciprocal():
     diag = jnp.array([[2.0, 4.0, 0.0, 0.0]])   # two zero-padded rows
     bands = jnp.zeros((1, 3, 4)).at[:, 1, :].set(diag)
     ops = fused_stacked_ops(bands, diag, offsets=(-1, 0, 1), plane=1)
-    z = ops.precond(jnp.array([[1.0, 1.0, 0.0, 0.0]]))
+    r = ops.pack(jnp.array([[1.0, 1.0, 0.0, 0.0]]))
+    z = ops.unpack(ops.precond(r))
     np.testing.assert_allclose(np.asarray(z), [[0.5, 0.25, 0.0, 0.0]])
     # the fused update step's dots stay finite too (r tail is exactly 0,
     # as the padding contract guarantees)
-    r = jnp.array([[1.0, 1.0, 0.0, 0.0]])
-    zero = jnp.zeros((1, 4))
+    zero = jnp.zeros_like(r)
     _, _, z2, rz, rr = ops.fused_step(zero, r, zero, zero, jnp.asarray(0.3))
     assert np.isfinite(np.asarray(z2)).all()
     assert np.isfinite(float(rz)) and np.isfinite(float(rr))
@@ -365,9 +444,15 @@ def test_cond_adds_no_reduction(solver):
 def test_resolve_backend():
     assert resolve_backend("fused", 8) == "fused"
     assert resolve_backend("reference", 1 << 22) == "reference"
-    assert resolve_backend("auto", FUSED_MIN_ROWS, on_tpu=True) == "fused"
-    assert resolve_backend("auto", FUSED_MIN_ROWS - 1,
-                           on_tpu=True) == "reference"
+    tpu = dict(on_tpu=True, precision="f32_ir")
+    assert resolve_backend("auto", FUSED_MIN_ROWS, **tpu) == "fused"
+    assert resolve_backend("auto", FUSED_MIN_ROWS - 1, **tpu) == "reference"
+    # no f64 into a Pallas TPU kernel: auto keeps the XLA path, an
+    # explicit fused request names the reason
+    assert resolve_backend("auto", 1 << 22, on_tpu=True) == "reference"
+    with pytest.raises(ValueError, match="f64"):
+        resolve_backend("fused", 1 << 22, on_tpu=True)
+    assert resolve_backend("fused", 1 << 22, on_tpu=False) == "fused"
     # off-TPU the kernels would run through the Pallas interpreter inside
     # the solve loop: auto never picks them (explicit "fused" still forces)
     assert resolve_backend("auto", 1 << 22, on_tpu=False) == "reference"
@@ -508,15 +593,16 @@ def test_refined_nan_rhs_signature(solver, policy):
 
 def test_resolve_backend_fused_min_rows_override(monkeypatch):
     monkeypatch.delenv("REPRO_FUSED_MIN_ROWS", raising=False)
-    assert resolve_backend("auto", 512, on_tpu=True,
+    tpu = dict(on_tpu=True, precision="f32_ir")
+    assert resolve_backend("auto", 512, **tpu,
                            fused_min_rows=256) == "fused"
-    assert resolve_backend("auto", 512, on_tpu=True,
+    assert resolve_backend("auto", 512, **tpu,
                            fused_min_rows=1024) == "reference"
     monkeypatch.setenv("REPRO_FUSED_MIN_ROWS", "128")
-    assert resolve_backend("auto", 128, on_tpu=True) == "fused"
-    assert resolve_backend("auto", 127, on_tpu=True) == "reference"
+    assert resolve_backend("auto", 128, **tpu) == "fused"
+    assert resolve_backend("auto", 127, **tpu) == "reference"
     # an explicit parameter wins over the environment
-    assert resolve_backend("auto", 127, on_tpu=True,
+    assert resolve_backend("auto", 127, **tpu,
                            fused_min_rows=64) == "fused"
 
 

@@ -1,5 +1,6 @@
 """Krylov solvers + distributed SpMV against dense references."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -33,6 +34,15 @@ def laplacian_buffers(mesh):
                       np.abs(iface[part, s]))
     diag += 1.0
     return layout, buffer_from_parts(diag, upper, lower, iface), diag
+
+
+def _ldu_offdiag(mesh, layout):
+    """The unit-conductance off-diagonal LDU blocks of ``laplacian_buffers``."""
+    P = mesh.n_parts
+    iface = -np.ones((P, layout.n_ifaces, layout.iface_size))
+    iface *= mesh.iface_mask()[:, :, None]
+    return (-np.ones((P, layout.n_faces)), -np.ones((P, layout.n_faces)),
+            iface)
 
 
 @pytest.mark.parametrize("alpha", [1, 2, 4])
@@ -162,27 +172,12 @@ def test_bicgstab_orthogonal_breakdown_keeps_iterate_finite():
 # ---- mixed-precision iterative refinement (ISSUE 10) ----------------------
 
 def _refined_reference_ops(policy, bands, diag_c, offsets, plane):
-    """Reference bundle under ``policy``: downcast operator closures for
-    the inner sweep + a full-precision matvec for the outer replay —
-    exactly what ``PisoSolver._solver_ops`` builds on the reference
-    backend."""
-    from repro.solvers.ops import reference_ops
-    from repro.solvers.precision import get_policy
+    """Reference bundle under ``policy`` — exactly what
+    ``PisoSolver._solver_ops`` builds on the reference backend."""
+    from repro.solvers.ops import stacked_reference_ops
 
-    pol = get_policy(policy)
-    bands_lo = bands.astype(pol.storage_dtype)
-    diag_lo = diag_c.astype(pol.storage_dtype)
-
-    def A_lo(v):
-        return spmv_dia(bands_lo, v, offsets=offsets, plane=plane)
-
-    def A_hi(v):
-        return spmv_dia(bands, v, offsets=offsets, plane=plane)
-
-    if pol.name == "f64":
-        return reference_ops(A_hi, jacobi_preconditioner(diag_c))
-    return reference_ops(A_lo, jacobi_preconditioner(diag_lo), policy=pol,
-                         matvec_hi=A_hi)
+    return stacked_reference_ops(bands, diag_c, offsets=offsets,
+                                 plane=plane, policy=policy)
 
 
 @pytest.mark.parametrize("solver", [cg, bicgstab])
@@ -220,3 +215,76 @@ def test_refined_reference_policies_meet_parity_gate(solver):
         # the low-precision iterate really was computed at low precision:
         # more total inner iterations than the straight f64 solve
         assert int(r.iters) >= int(res["f64"].iters), pol
+
+
+@pytest.mark.parametrize("policy", ["f32_ir", "bf16_ir"])
+def test_refined_cg_on_reference_pinned_neumann_laplacian(policy):
+    """The pressure matrix shape: an all-Neumann Laplacian made definite
+    by boosting one reference cell.  Its smallest eigenvalue shrinks like
+    1/cells, so low-precision sweeps amplify the constant mode; with it
+    deflated out of the sweeps (and solved in f64) the refined answer
+    meets the f64 parity gate."""
+    mesh = CavityMesh.cube(16, 2)
+    layout, buffers, diag = laplacian_buffers(mesh)
+    diag = diag - 1.0                        # drop the +I: all-Neumann
+    diag[0, 0] *= 2.0                        # reference-cell boost
+    buffers = buffer_from_parts(diag, *_ldu_offdiag(mesh, layout))
+    plan = plan_for_mesh(mesh, 2)
+    bands = update_device_direct(plan, jnp.asarray(buffers).reshape(1, 2, -1),
+                                 target="dia")
+    offsets = tuple(int(o) for o in plan.dia_offsets)
+    diag_c = jnp.asarray(diag).reshape(1, -1)
+    rng = np.random.default_rng(12)
+    b = jnp.asarray(rng.standard_normal((1, plan.m_coarse)))
+    b = b / jnp.linalg.norm(b)
+    res = {pol: cg(_refined_reference_ops(pol, bands, diag_c, offsets,
+                                          plan.plane),
+                   b, jnp.zeros_like(b), tol=1e-12, maxiter=2000)
+           for pol in ("f64", policy)}
+    assert bool(res["f64"].converged)
+    r = res[policy]
+    assert bool(r.converged) and not bool(r.hit_cap)
+    diff = float(np.max(np.abs(np.asarray(r.x) - np.asarray(res["f64"].x))))
+    assert diff <= 1e-10 * float(np.max(np.abs(np.asarray(res["f64"].x))))
+
+
+def _pinned_neumann_dia(n, parts):
+    """The 7-band pressure matrix of an n³ cavity at uniform rAU — the
+    all-Neumann Laplacian with the reference cell's diagonal doubled, as
+    ``assemble_pressure_matrix`` builds it — stacked over z-slab parts."""
+    plane = n * n
+    m = plane * (n // parts)
+    g = np.arange(parts * m)
+    z, y, x = g // plane, (g % plane) // n, g % n
+    h = 1.0 / n
+    has = [z > 0, y > 0, x > 0, None, x < n - 1, y < n - 1, z < n - 1]
+    bands = np.stack([np.zeros(g.size) if ok is None else -h * ok
+                      for ok in has])
+    bands[3] = -bands.sum(0)
+    bands[3, 0] *= 2.0
+    offsets = (-plane, -n, -1, 0, 1, n, plane)
+    return (jnp.asarray(bands.reshape(7, parts, m).transpose(1, 0, 2)),
+            offsets, plane)
+
+
+def test_refined_cg_pinned_neumann_laplacian_at_scale():
+    """At 80³ in f32 the rounded plain-form bands leave row sums whose
+    total rivals the reference cell's pin, and the refined sweeps
+    diverged; in difference form f32_ir meets the f64 tolerance in a few
+    outer passes.  The rhs is mean-free, like a divergence source."""
+    bands, offsets, plane = _pinned_neumann_dia(80, 5)
+    diag = bands[:, 3, :]
+    P, _, m = bands.shape
+    zz = (np.arange(P * m) // plane).reshape(P, m) / 80
+    rng = np.random.default_rng(14)
+    b = 1e-3 * rng.standard_normal((P, m)) + np.exp(-((zz - 1) / .05) ** 2)
+    b = jnp.asarray(b - b.mean())
+    res = {pol: jax.jit(lambda b, pol=pol: cg(
+        _refined_reference_ops(pol, bands, diag, offsets, plane), b,
+        jnp.zeros_like(b), tol=1e-8, maxiter=2000))(b)
+        for pol in ("f64", "f32_ir")}
+    assert bool(res["f64"].converged)
+    r = res["f32_ir"]
+    assert bool(r.converged) and not bool(r.hit_cap)
+    assert 1 <= int(r.outer_iters) <= 4
+    assert int(r.iters) <= 2 * int(res["f64"].iters)
