@@ -1,0 +1,7 @@
+"""Device microseconds per call of the fused band SpMV + p.Ap kernel in
+the coarse pressure CG (one call per inner iteration)."""
+from kernel_calls import per_call_us
+
+
+def read(run):
+    return per_call_us(run, "spmv_dot")
