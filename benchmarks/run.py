@@ -1,4 +1,4 @@
-"""Benchmark driver — one module per paper figure + roofline/kernels.
+"""Figure benchmarks — one module per paper figure.
 
 Prints ``name,us_per_call,derived`` CSV (benchmarks/common.emit).
 
@@ -23,8 +23,7 @@ def main() -> None:
                             fig9_gpu_aware, fig10_adaptive,
                             fig11_fused_krylov, fig12_step_program,
                             fig13_engine_throughput, fig14_cases,
-                            fig15_supervision, hillclimb, kernels_bench,
-                            roofline)
+                            fig15_supervision, hillclimb)
 
     suites = {
         "fig4": fig4_lsp_vs_alpha.run,
@@ -40,8 +39,6 @@ def main() -> None:
         "fig13": fig13_engine_throughput.run,
         "fig14": fig14_cases.run,
         "fig15": fig15_supervision.run,
-        "kernels": kernels_bench.run,
-        "roofline": roofline.run,
         "cfd_dryrun": cfd_dryrun.run,
         "cfd_modes": cfd_modes.run,
         "hillclimb": hillclimb.run,

@@ -66,6 +66,9 @@ class PisoState(NamedTuple):
 class StepStats(NamedTuple):
     mom_iters: jax.Array
     p_iters: jax.Array        # (n_correctors,)
+    # (n_correctors,): the f64 refinement passes of each pressure solve
+    # (CGResult.outer_iters; 0 on the f64 policy)
+    p_outer: jax.Array
     continuity_err: jax.Array  # max |div(phi)| after correction
     p_residual: jax.Array
     # compiled health signals (step_program.health_flags): every Krylov
@@ -476,6 +479,13 @@ class SegregatedSolver:
         """
         return self._stepper.run_steps(state, dt, n_steps,
                                        *self._extras())
+
+    def lower_steps(self, state: PisoState, dt: float, n_steps: int):
+        """The compiled window that :meth:`run_steps` dispatches for
+        ``n_steps`` (its HLO names each phase in ``op_name``).  ``state``
+        may hold ``jax.ShapeDtypeStruct`` leaves."""
+        return self._stepper.lower_steps(state, dt, n_steps,
+                                         *self._extras())
 
     def batched_executor(self, batch: int):
         """The cohort stepper for ``batch`` stacked sessions.

@@ -51,12 +51,13 @@ __all__ = ["SimpleStats", "build_simple_program"]
 class SimpleStats(NamedTuple):
     """Per-outer-iteration residuals (the convergence predicate's input).
 
-    Field layout mirrors ``StepStats`` (mom_iters / p_iters /
+    Field layout mirrors ``StepStats`` (mom_iters / p_iters / p_outer /
     continuity_err / p_residual) so serving-side consumers can treat the
     two uniformly, plus the outer velocity change ``u_delta``."""
 
     mom_iters: jax.Array
     p_iters: jax.Array         # (1,) — one correction per outer iteration
+    p_outer: jax.Array         # (1,) — its f64 refinement passes
     continuity_err: jax.Array  # max |div(phi)| / V after correction
     p_residual: jax.Array
     u_delta: jax.Array         # max |U - U_prev| over the outer iteration
@@ -119,7 +120,8 @@ def build_simple_program(solver) -> StepProgram:
         Phase("update_p", "update", ("sysP",), ("bandsP",), tk.update_p,
               instrumented_fn=tk.update_p_inst),
         Phase("solve_p", "solve", ("bandsP", "sysP", "p"),
-              ("p_new", "p_iters_0", "p_res", "p_ok_0", "p_cap_0"),
+              ("p_new", "p_iters_0", "p_res", "p_ok_0", "p_cap_0",
+               "p_outer_0"),
               tk.solve_p,
               probe=tk.halo_probe, probe_inputs=("p",),
               probe_iters="p_iters_0"),
@@ -169,6 +171,7 @@ def build_simple_program(solver) -> StepProgram:
         stats = SimpleStats(
             mom_iters=env["mom_iters"],
             p_iters=jnp.stack([env["p_iters_0"]]),
+            p_outer=jnp.stack([env["p_outer_0"]]),
             continuity_err=env["cont"],
             p_residual=env["p_res"],
             u_delta=env["u_delta"],

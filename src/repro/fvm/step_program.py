@@ -49,7 +49,11 @@ definition:
   :class:`BatchedPipelinedExecutor` is its cohort (vmapped) variant.
 
 Every future phase change (overlap, mixed precision, extra correctors) is
-a one-place edit to the phase list; all executors pick it up.
+a one-place edit to the phase list; all executors pick it up.  Each
+executor that traces the whole program runs every phase under
+``jax.named_scope(<phase name>)`` (:func:`_run_phase`), so the compiled
+window's HLO metadata names the phase of each instruction a profiler
+trace times.
 """
 from __future__ import annotations
 
@@ -132,6 +136,16 @@ def _bind(env: dict, phase: Phase, out) -> None:
             f"phase {phase.label} returned {len(out)} values for outputs "
             f"{phase.outputs}")
     env.update(zip(phase.outputs, out))
+
+
+def _run_phase(env: dict, phase: Phase) -> None:
+    """Run one phase of a traced program on ``env`` under
+    ``jax.named_scope(phase.name)``: the compiled HLO's ``op_name``
+    metadata then names the phase of every instruction it issues (the
+    correctors of a phase share one scope).  Metadata only — the
+    computation is the same."""
+    with jax.named_scope(phase.name):
+        _bind(env, phase, phase.fn(*(env[k] for k in phase.inputs)))
 
 
 def _timed_phase_walk(program: StepProgram, fns: dict, probes: dict,
@@ -325,12 +339,15 @@ def _pipeline_step_fn(program: StepProgram) -> Callable:
     prime = form.prime
 
     def step(state, dt, *extra):
-        env = program.seed(state, dt, *extra)
+        with jax.named_scope("seed"):
+            env = program.seed(state, dt, *extra)
         if prime is not None:
-            env.update(prime(env))
+            with jax.named_scope("prime"):
+                env.update(prime(env))
         for ph in schedule:
-            _bind(env, ph, ph.fn(*(env[k] for k in ph.inputs)))
-        return program.finalize(env)
+            _run_phase(env, ph)
+        with jax.named_scope("finalize"):
+            return program.finalize(env)
 
     return step
 
@@ -350,17 +367,21 @@ def _pipeline_rolled_fn(program: StepProgram, n_steps: int) -> Callable:
     prime = form.prime
 
     def rolled(state, dt, *extra):
-        env0 = program.seed(state, dt, *extra)
-        primed = prime(env0) if prime is not None else {}
+        with jax.named_scope("seed"):
+            env0 = program.seed(state, dt, *extra)
+        with jax.named_scope("prime"):
+            primed = prime(env0) if prime is not None else {}
         ring0 = tuple(primed[k] for k in ring_keys)
 
         def body(carry, _):
             st, ring = carry
-            env = program.seed(st, dt, *extra)
+            with jax.named_scope("seed"):
+                env = program.seed(st, dt, *extra)
             env.update(zip(ring_keys, ring))
             for ph in schedule:
-                _bind(env, ph, ph.fn(*(env[k] for k in ph.inputs)))
-            st2, stats = program.finalize(env)
+                _run_phase(env, ph)
+            with jax.named_scope("finalize"):
+                st2, stats = program.finalize(env)
             return (st2, tuple(env[k] for k in ring_keys)), stats
 
         (state, _), stats = jax.lax.scan(body, (state, ring0), None,
@@ -464,10 +485,12 @@ class StepProgram:
         """The pure ``(state, dt, *extras) -> (state, stats)`` composition."""
 
         def step(state, dt, *extra):
-            env = self.seed(state, dt, *extra)
+            with jax.named_scope("seed"):
+                env = self.seed(state, dt, *extra)
             for ph in self.phases:
-                _bind(env, ph, ph.fn(*(env[k] for k in ph.inputs)))
-            return self.finalize(env)
+                _run_phase(env, ph)
+            with jax.named_scope("finalize"):
+                return self.finalize(env)
 
         return step
 
@@ -535,6 +558,15 @@ class FusedExecutor:
     def lower_step(self, state, dt, *extra):
         """Lowered+compiled per-step executable (donation/HLO inspection)."""
         return self._step.lower(state, dt, *extra).compile()
+
+    def lower_steps(self, state, dt, n_steps: int, *extra):
+        """The compiled window that ``run_steps(state, dt, n_steps)``
+        dispatches: the same memoized jit, lowered and compiled (a hit in
+        JAX's compilation caches where that window ran).  ``state`` may be
+        ``jax.ShapeDtypeStruct`` leaves, so a caller can ask after the
+        state was donated."""
+        roll = _memoized_roll(self._rolled, self._fn, n_steps)
+        return roll.lower(state, dt, *extra).compile()
 
 
 # ---------------------------------------------------------------------------
@@ -749,14 +781,18 @@ class PipelinedExecutor:
         self.dispatches += 1
         return self._step(state, dt, *extra)
 
-    def run_steps(self, state, dt, n_steps: int, *extra):
-        """``n_steps`` pipelined timesteps as ONE dispatch; stacked
-        ``StepStats``; donates ``state``; memoized per window length."""
+    def _roll(self, n_steps: int) -> Callable:
         n = int(n_steps)
         roll = self._rolled.get(n)
         if roll is None:
             roll = self._rolled[n] = jax.jit(
                 _pipeline_rolled_fn(self.program, n), donate_argnums=(0,))
+        return roll
+
+    def run_steps(self, state, dt, n_steps: int, *extra):
+        """``n_steps`` pipelined timesteps as ONE dispatch; stacked
+        ``StepStats``; donates ``state``; memoized per window length."""
+        roll = self._roll(n_steps)
         self.dispatches += 1
         return roll(state, dt, *extra)
 
@@ -779,6 +815,11 @@ class PipelinedExecutor:
     def lower_step(self, state, dt, *extra):
         """Lowered+compiled per-step executable (donation/HLO inspection)."""
         return self._step.lower(state, dt, *extra).compile()
+
+    def lower_steps(self, state, dt, n_steps: int, *extra):
+        """The compiled window that ``run_steps(state, dt, n_steps)``
+        dispatches (see :meth:`FusedExecutor.lower_steps`)."""
+        return self._roll(n_steps).lower(state, dt, *extra).compile()
 
 
 class BatchedPipelinedExecutor:
@@ -1115,7 +1156,7 @@ def _phase_toolkit(solver) -> PhaseToolkit:
         opsP = solver._solver_ops(plan_p, bandsP, diag_c)
         sol = cg(opsP, b_c, x0_c, tol=p_tol, maxiter=p_maxiter)
         return (sol.x.reshape(p.shape), sol.iters, sol.residual,
-                sol.converged, sol.hit_cap)
+                sol.converged, sol.hit_cap, sol.outer_iters)
 
     def halo_probe(p):
         return x_pad(p.reshape(n_c, -1), plan_p.plane)
@@ -1248,7 +1289,8 @@ def build_piso_program(solver) -> StepProgram:
             Phase("update_p", "update", ("sysP",), ("bandsP",), tk.update_p,
                   corrector=i, instrumented_fn=tk.update_p_inst),
             Phase("solve_p", "solve", ("bandsP", "sysP", "p"),
-                  ("p", f"p_iters_{i}", "p_res", f"p_ok_{i}", f"p_cap_{i}"),
+                  ("p", f"p_iters_{i}", "p_res", f"p_ok_{i}", f"p_cap_{i}",
+                   f"p_outer_{i}"),
                   tk.solve_p, corrector=i,
                   probe=tk.halo_probe, probe_inputs=("p",),
                   probe_iters=f"p_iters_{i}"),
@@ -1292,6 +1334,7 @@ def build_piso_program(solver) -> StepProgram:
         stats = StepStats(
             mom_iters=env["mom_iters"],
             p_iters=jnp.stack([env[f"p_iters_{i}"] for i in range(n_corr)]),
+            p_outer=jnp.stack([env[f"p_outer_{i}"] for i in range(n_corr)]),
             continuity_err=env["cont"],
             p_residual=env["p_res"],
             converged=converged, diverged=diverged, hit_cap=hit_cap)
@@ -1329,7 +1372,8 @@ def build_piso_program(solver) -> StepProgram:
                   ("HbyA", "phiH", "phiH_if", "phiH_b", "sysP"),
                   tk.assemble_p_src, corrector=i),
             Phase("solve_p", "solve", ("bandsP", "sysP", "p"),
-                  ("p", f"p_iters_{i}", "p_res", f"p_ok_{i}", f"p_cap_{i}"),
+                  ("p", f"p_iters_{i}", "p_res", f"p_ok_{i}", f"p_cap_{i}",
+                   f"p_outer_{i}"),
                   tk.solve_p, corrector=i, blocking=True,
                   probe=tk.halo_probe, probe_inputs=("p",),
                   probe_iters=f"p_iters_{i}"),

@@ -21,9 +21,8 @@ Layout & padding contract (``coef_update.py``):
 Entry point: :func:`~repro.kernels.coef_update.ops.coef_update_pallas`
 (stacked coarse parts, interpret-mode fallback off-TPU).  ``ref.py`` is the
 jnp oracle (``buf[src]``); bit-exact agreement per dtype is enforced by
-``tests/test_kernels.py`` and timed by ``benchmarks/kernels_bench.py``
-(docs/kernels.md).  The jit-level analogue used inside the PISO step — with
-compiled-program reuse across equal-shape plans — is
-:class:`repro.core.update.UpdaterPool`.
+``tests/test_kernels.py`` (docs/kernels.md).  The jit-level analogue used
+inside the PISO step — with compiled-program reuse across equal-shape
+plans — is :class:`repro.core.update.UpdaterPool`.
 """
 from repro.kernels.coef_update.ops import coef_update_pallas  # noqa: F401

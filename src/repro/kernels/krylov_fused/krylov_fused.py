@@ -191,6 +191,7 @@ def axpy_precond_lanes(x: jax.Array, r: jax.Array, p: jax.Array,
             rows * LANES, x.dtype.itemsize, block_rows=block_rows,
             accum_itemsize=jnp.dtype(accum_dtype).itemsize)),
         interpret=interpret,
+        name="axpy_precond",
     )(jnp.reshape(alpha, (1,)).astype(accum_dtype), x, r, p, Ap, inv_diag)
     return xn, rn, z, jnp.sum(rz), jnp.sum(rr)
 

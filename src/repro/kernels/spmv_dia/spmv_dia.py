@@ -272,6 +272,7 @@ def band_call(bands: jax.Array, x_pad: jax.Array, *,
                 x_pad.size, nb, block_rows, bands.dtype, accum_dtype)),
         cost_estimate=cost_estimate,
         interpret=interpret,
+        name="spmv_dot" if with_dot else "spmv",
     )(bands, x_pad)
 
 

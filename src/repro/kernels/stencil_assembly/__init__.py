@@ -23,7 +23,6 @@ Layout & tiling contract (``stencil_assembly.py``):
 Entry points: :func:`~repro.kernels.stencil_assembly.ops.momentum_bands_pallas`
 (stacked parts, interpret-mode fallback off-TPU) and
 ``momentum_bands_single``.  ``ref.py`` is the jnp oracle; the contract is
-bit-exact agreement per dtype (``tests/test_kernels.py``), timed by
-``benchmarks/kernels_bench.py`` (docs/kernels.md).
+bit-exact agreement per dtype (``tests/test_kernels.py``; docs/kernels.md).
 """
 from repro.kernels.stencil_assembly.ops import momentum_bands_pallas  # noqa: F401

@@ -22,7 +22,7 @@ For each cell this:
   4. parses the partitioned HLO for collective ops (all-gather/all-reduce/
      reduce-scatter/all-to-all/collective-permute) and sums their bytes —
      cost_analysis does not report them,
-  5. writes one JSON per cell under --out (consumed by benchmarks/roofline).
+  5. writes one JSON per cell under --out.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k \

@@ -9,7 +9,8 @@ from repro.fvm.piso import StepStats
 
 def _stats(converged, diverged, hit_cap):
     z = jnp.zeros(())
-    return StepStats(mom_iters=z, p_iters=jnp.zeros((2,)), continuity_err=z,
+    return StepStats(mom_iters=z, p_iters=jnp.zeros((2,)),
+                     p_outer=jnp.zeros((2,)), continuity_err=z,
                      p_residual=z, converged=jnp.asarray(converged),
                      diverged=jnp.asarray(diverged),
                      hit_cap=jnp.asarray(hit_cap))

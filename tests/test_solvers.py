@@ -217,6 +217,40 @@ def test_refined_reference_policies_meet_parity_gate(solver):
         assert int(r.iters) >= int(res["f64"].iters), pol
 
 
+@pytest.mark.parametrize("precision", ["f64", "f32_ir"])
+def test_step_stats_p_outer_is_the_cg_refinement_passes(precision,
+                                                        monkeypatch):
+    """``StepStats.p_outer`` holds each corrector's pressure-CG
+    ``outer_iters``, in corrector order: the f64 refinement passes under
+    ``f32_ir``, 0 under ``f64``."""
+    import importlib
+
+    from repro.fvm.piso import PisoSolver
+
+    cg_module = importlib.import_module("repro.solvers.cg")
+
+    seen = []
+    real = cg_module.cg
+
+    def spy(*args, **kw):
+        res = real(*args, **kw)
+        jax.debug.callback(lambda k: seen.append(int(k)), res.outer_iters,
+                           ordered=True)
+        return res
+
+    monkeypatch.setattr(cg_module, "cg", spy)
+    solver = PisoSolver(CavityMesh.cube(4, 2), alpha=2, precision=precision)
+    _, stats = solver.step(solver.initial_state(), 1e-3)
+    jax.block_until_ready(stats)
+    jax.effects_barrier()
+    assert stats.p_outer.shape == (solver.n_correctors,)
+    assert stats.p_outer.tolist() == seen
+    if precision == "f64":
+        assert seen == [0] * solver.n_correctors
+    else:
+        assert min(seen) >= 1
+
+
 @pytest.mark.parametrize("policy", ["f32_ir", "bf16_ir"])
 def test_refined_cg_on_reference_pinned_neumann_laplacian(policy):
     """The pressure matrix shape: an all-Neumann Laplacian made definite

@@ -423,6 +423,31 @@ def test_pipelined_schedule_and_frontier():
     assert PHASE_TAGS == PhaseBreakdown.TIME_FIELDS
 
 
+@pytest.mark.parametrize("pipeline", ["off", "on"])
+def test_window_hlo_names_every_phase(pipeline):
+    """The compiled window of ``run_steps`` (the fused executor's, and the
+    pipelined one's) carries each phase name of the schedule that runs as
+    a component of some instruction's ``op_name``, beside the finalize
+    scope; it lowers from ``ShapeDtypeStruct`` leaves, as a caller
+    holding only a donated state's shapes asks for it.  Two steps: in a
+    one-step pipelined window the trailing ``grad_p`` feeds only the
+    dropped ring, and the compiler removes it."""
+    import re
+
+    s = PisoSolver(CavityMesh.cube(4, 2), alpha=2, pipeline=pipeline)
+    shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                          fresh(s))
+    exe = s._exec.pipelined if s.pipelined else s._exec.fused
+    assert "solve_p" in s.lower_steps(shapes, DT, 1).as_text()
+    hlo = exe.lower_steps(shapes, DT, 2).as_text()
+    scopes = {part for op in re.findall(r'op_name="([^"]*)"', hlo)
+              for part in op.split("/")}
+    phases = s.program.pipeline.phases if s.pipelined else s.program.phases
+    names = {ph.name for ph in phases}
+    assert len(names) == (9 if s.pipelined else 7)
+    assert names | {"finalize"} <= scopes, sorted(scopes)
+
+
 def test_pipelined_donates_state_and_aliases_buffers():
     s = PisoSolver(CavityMesh.cube(4, 2), alpha=2, pipeline="on")
     st = fresh(s)

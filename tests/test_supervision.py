@@ -95,6 +95,7 @@ def test_window_verdict_semantics():
     def stats(diverged, hit_cap):
         return StepStats(
             mom_iters=jnp.zeros(4), p_iters=jnp.zeros((4, 2)),
+            p_outer=jnp.zeros((4, 2)),
             continuity_err=jnp.zeros(4), p_residual=jnp.zeros(4),
             converged=jnp.ones(4, bool) & ~jnp.asarray(diverged),
             diverged=jnp.asarray(diverged), hit_cap=jnp.asarray(hit_cap))
