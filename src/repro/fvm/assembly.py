@@ -36,7 +36,7 @@ import numpy as np
 
 from repro.fvm.cases import (FlowCase, INLET, MOVING_WALL, OUTLET, PatchBC,
                              get_case)
-from repro.core.rows import add_rows, take_rows
+from repro.core.rows import RowSet, add_rows, take_rows
 from repro.fvm.mesh import CavityMesh, DOWN, UP
 from repro.sparse.distributed import halo_exchange
 
@@ -106,17 +106,18 @@ class CavityAssembly:
                                          U=(lid_speed, 0.0, 0.0))})
         self.case = get_case(case)
         P = mesh.n_parts
-        self.owner = jnp.asarray(mesh.owner, jnp.int32)
-        self.neigh = jnp.asarray(mesh.neigh, jnp.int32)
+        m = mesh.n_cells
+        self.owner = RowSet.build(mesh.owner, m)
+        self.neigh = RowSet.build(mesh.neigh, m)
         self.face_axis = jnp.asarray(mesh.face_axis, jnp.int32)
-        ifs = mesh.ifaces
-        self.if_rows = jnp.asarray(np.stack([s.rows for s in ifs]), jnp.int32)
+        # (DOWN, UP) interface row sets
+        self.if_rows = tuple(RowSet.build(s.rows, m) for s in mesh.ifaces)
         # (P, 2) presence mask for interfaces, broadcast over faces
         self.if_mask = jnp.asarray(mesh.iface_mask(), dtype)[:, :, None]
         # boundary patches: per-patch BC kind + Dirichlet velocity, bound
         # from the case by geometric role.  patch_Ub entries are (3,)
         # uniform values or (n_bf, 3) per-face values (profiled inlets).
-        self.patch_rows = [jnp.asarray(p.rows, jnp.int32) for p in mesh.patches]
+        self.patch_rows = [RowSet.build(p.rows, m) for p in mesh.patches]
         self.patch_mask = jnp.asarray(mesh.patch_mask(), dtype)  # (P, n_patches)
         self.patch_kind = [self.case.bc(_patch_role(p.normal)).kind
                            for p in mesh.patches]
@@ -126,7 +127,7 @@ class CavityAssembly:
         self.h = mesh.h
         self.plane = mesh.plane
         self.n_parts = P
-        self.m = mesh.n_cells
+        self.m = m
         # outward z-normal per patch, for dynamic part-activity masks: the
         # +z patch rides on the last active part, the -z patch on part 0;
         # everything else is on every active part
@@ -209,7 +210,8 @@ class CavityAssembly:
         phi_f = 0.5*(U_o + U_n)[axis] * A, oriented owner→neigh.  Interface
         fluxes are *outward* of the owning part (down: -z, up: +z).
         """
-        Uf = 0.5 * (take_rows(U, self.owner) + take_rows(U, self.neigh))
+        Uf = 0.5 * (take_rows(U, self.owner.idx)
+                    + take_rows(U, self.neigh.idx))
         ax = self.face_axis
         comp = jnp.where(ax == 0, Uf[..., 0],
                          jnp.where(ax == 1, Uf[..., 1], Uf[..., 2]))
@@ -217,8 +219,8 @@ class CavityAssembly:
         # interface: halo of w-velocity planes
         w = U[..., 2]
         down, up = halo_exchange(w, self.plane)  # remote plane values
-        w_down_local = take_rows(w, self.if_rows[DOWN])
-        w_up_local = take_rows(w, self.if_rows[UP])
+        w_down_local = take_rows(w, self.if_rows[DOWN].idx)
+        w_up_local = take_rows(w, self.if_rows[UP].idx)
         phi_down = -self.A * 0.5 * (w_down_local + down)   # outward -z
         phi_up = +self.A * 0.5 * (w_up_local + up)         # outward +z
         phi_if = jnp.stack([phi_down, phi_up], axis=1) * self.if_mask
@@ -242,7 +244,7 @@ class CavityAssembly:
             mask = self.patch_mask[:, pi]
             nz = self._patch_nz[pi]
             if self.patch_kind[pi] == OUTLET:
-                f = take_rows(U[..., 2], rows) * (nz * self.A)
+                f = take_rows(U[..., 2], rows.idx) * (nz * self.A)
             else:
                 w = jnp.atleast_2d(self.patch_Ub[pi])[:, 2]  # (1,) or (B,)
                 f = jnp.broadcast_to(w * (nz * self.A), (P, self.plane))
@@ -256,16 +258,18 @@ class CavityAssembly:
         """(P, m, 3) Gauss gradient of a cell scalar field."""
         P, m = p.shape
         g = jnp.zeros((P, m, 3), self.dtype)
-        pf = 0.5 * (take_rows(p, self.owner) + take_rows(p, self.neigh))
+        pf = 0.5 * (take_rows(p, self.owner.idx)
+                    + take_rows(p, self.neigh.idx))
         sf = jax.nn.one_hot(self.face_axis, 3, dtype=self.dtype) * self.A  # (F,3)
         contrib = pf[:, :, None] * sf[None, :, :]
         g = add_rows(g, self.owner, contrib)
         g = add_rows(g, self.neigh, -contrib)
         # interfaces: S = ±A e_z outward
         down, up = halo_exchange(p, self.plane)
-        pf_down = (0.5 * (take_rows(p, self.if_rows[DOWN]) + down)
+        pf_down = (0.5 * (take_rows(p, self.if_rows[DOWN].idx) + down)
                    * self.if_mask[:, DOWN])
-        pf_up = 0.5 * (take_rows(p, self.if_rows[UP]) + up) * self.if_mask[:, UP]
+        pf_up = (0.5 * (take_rows(p, self.if_rows[UP].idx) + up)
+                 * self.if_mask[:, UP])
         gz = add_rows(g[..., 2], self.if_rows[DOWN], -self.A * pf_down)
         gz = add_rows(gz, self.if_rows[UP], self.A * pf_up)
         g = g.at[..., 2].set(gz)
@@ -278,7 +282,7 @@ class CavityAssembly:
             if kind == OUTLET:
                 continue
             n = jnp.asarray(patch.normal, self.dtype)
-            pb = take_rows(p, rows) * mask[:, None]
+            pb = take_rows(p, rows.idx) * mask[:, None]
             g = add_rows(g, rows, pb[:, :, None] * (self.A * n)[None, None, :])
         return g / self.V
 
@@ -371,8 +375,8 @@ class CavityAssembly:
     def offdiag_apply(self, sys, x: jax.Array) -> jax.Array:
         """y = (A - diag) x on the fine partition (for OpenFOAM's H())."""
         y = jnp.zeros_like(x)
-        y = add_rows(y, self.owner, sys.upper * take_rows(x, self.neigh))
-        y = add_rows(y, self.neigh, sys.lower * take_rows(x, self.owner))
+        y = add_rows(y, self.owner, sys.upper * take_rows(x, self.neigh.idx))
+        y = add_rows(y, self.neigh, sys.lower * take_rows(x, self.owner.idx))
         down, up = halo_exchange(x, self.plane)
         y = add_rows(y, self.if_rows[DOWN], sys.iface[:, DOWN] * down)
         y = add_rows(y, self.if_rows[UP], sys.iface[:, UP] * up)
@@ -394,12 +398,14 @@ class CavityAssembly:
         Returns a :class:`PressureSystem` with a **zero** source.
         """
         P, m = rAU.shape
-        rAUf = 0.5 * (take_rows(rAU, self.owner) + take_rows(rAU, self.neigh))
+        rAUf = 0.5 * (take_rows(rAU, self.owner.idx)
+                      + take_rows(rAU, self.neigh.idx))
         g_int = rAUf * self.A / self.h
         down, up = halo_exchange(rAU, self.plane)
-        g_down = (0.5 * (take_rows(rAU, self.if_rows[DOWN]) + down)
+        g_down = (0.5 * (take_rows(rAU, self.if_rows[DOWN].idx) + down)
                   * self.A / self.h)
-        g_up = 0.5 * (take_rows(rAU, self.if_rows[UP]) + up) * self.A / self.h
+        g_up = (0.5 * (take_rows(rAU, self.if_rows[UP].idx) + up)
+                * self.A / self.h)
         g_if = jnp.stack([g_down, g_up], axis=1) * self.if_mask
 
         diag = jnp.zeros((P, m), self.dtype)
@@ -417,7 +423,7 @@ class CavityAssembly:
             if self.patch_kind[pi] != OUTLET:
                 continue
             rows = self.if_rows[slot]
-            gb = take_rows(rAU, rows) * (self.A / (0.5 * self.h))
+            gb = take_rows(rAU, rows.idx) * (self.A / (0.5 * self.h))
             g_b = g_b.at[:, slot].set(gb * self.patch_mask[:, pi][:, None])
             diag = add_rows(diag, rows, g_b[:, slot])
 
@@ -455,11 +461,11 @@ class CavityAssembly:
 
     def correct_flux(self, sysP: PressureSystem, phiHbyA, phiHbyA_if, p):
         """phi = phiHbyA - g_f (p_n - p_o); conservative by construction."""
-        dp = take_rows(p, self.neigh) - take_rows(p, self.owner)
+        dp = take_rows(p, self.neigh.idx) - take_rows(p, self.owner.idx)
         phi = phiHbyA - sysP.g_int * dp
         down, up = halo_exchange(p, self.plane)
-        dp_down = down - take_rows(p, self.if_rows[DOWN])  # outward (-z)
-        dp_up = up - take_rows(p, self.if_rows[UP])
+        dp_down = down - take_rows(p, self.if_rows[DOWN].idx)  # outward (-z)
+        dp_up = up - take_rows(p, self.if_rows[UP].idx)
         phi_if = phiHbyA_if - jnp.stack(
             [sysP.g_if[:, DOWN] * dp_down, sysP.g_if[:, UP] * dp_up], axis=1)
         return phi, phi_if * self.if_mask
@@ -474,6 +480,6 @@ class CavityAssembly:
         ``fixedValue`` pressure-flux correction).
         """
         corr = jnp.stack(
-            [sysP.g_b[:, DOWN] * take_rows(p, self.if_rows[DOWN]),
-             sysP.g_b[:, UP] * take_rows(p, self.if_rows[UP])], axis=1)
+            [sysP.g_b[:, DOWN] * take_rows(p, self.if_rows[DOWN].idx),
+             sysP.g_b[:, UP] * take_rows(p, self.if_rows[UP].idx)], axis=1)
         return phiHbyA_b + corr

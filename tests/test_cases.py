@@ -73,7 +73,7 @@ def test_cavity_case_path_is_bitwise_identical_to_legacy():
     phi_b = cased.boundary_flux(U)
     assert float(jnp.abs(phi_b).max()) == 0.0
 
-    phi = jnp.zeros((mesh.n_parts, legacy.owner.shape[0]), jnp.float64)
+    phi = jnp.zeros((mesh.n_parts, mesh.n_faces), jnp.float64)
     phi_if = jnp.zeros((mesh.n_parts, 2, legacy.plane), jnp.float64)
     p = jnp.zeros((mesh.n_parts, mesh.n_cells), jnp.float64)
     a = legacy.assemble_momentum(U, phi, phi_if, p, 1e-3)

@@ -448,6 +448,24 @@ def test_window_hlo_names_every_phase(pipeline):
     assert names | {"finalize"} <= scopes, sorted(scopes)
 
 
+@pytest.mark.parametrize("case", ["cavity", "backstep"])
+@pytest.mark.parametrize("pipeline", ["off", "on"])
+def test_window_hlo_has_no_scatter(case, pipeline):
+    """The assembly's face sums are gathers over static row tables
+    (``core.rows.add_rows``): the compiled PISO window, serial and
+    pipelined, of a cavity and of an inlet/outlet case holds no scatter
+    instruction."""
+    import re
+
+    s = PisoSolver(CavityMesh.cube(10, 2), alpha=2, pipeline=pipeline,
+                   case=case)
+    shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                          fresh(s))
+    hlo = s.lower_steps(shapes, DT, 1).as_text()
+    assert re.search(r"[ )]gather\(", hlo)
+    assert not re.findall(r"[ )]scatter\(", hlo)
+
+
 def test_pipelined_donates_state_and_aliases_buffers():
     s = PisoSolver(CavityMesh.cube(4, 2), alpha=2, pipeline="on")
     st = fresh(s)

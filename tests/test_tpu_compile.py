@@ -7,15 +7,21 @@ calls — so these compiles guard the layout contract of ``spmv_dia`` and
 ``krylov_fused`` at no chip time.  The size is one coarse part of the
 paper's 210³ lidDrivenCavity3D at ``--parts 10 --alpha 2``: ``m =
 210 * 210 * 21 * 2 = 1,852,200`` rows with ``x_pad`` resident in VMEM.
+The assembly's face sums compile too, at the benchmark's 70³, as gathers
+with no scatter.
 
 The topology is described inside a fixture (never at import): only the
 worker that runs this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.fvm.assembly import CavityAssembly
+from repro.fvm.mesh import CavityMesh
 from repro.kernels.krylov_fused.krylov_fused import (
     fused_axpy_precond_single, spmv_dot_single)
 from repro.kernels.spmv_dia.spmv_dia import pick_block_rows, spmv_dia_single
@@ -82,3 +88,14 @@ def test_f64_bands_refused_before_lowering(one_chip):
                               sharding=one_chip)
     with pytest.raises(ValueError, match="f64 custom call"):
         spmv_dot_single.lower(f64, xp, offsets=OFFSETS, plane=PLANE)
+
+
+def test_assembly_face_sums_compile_without_scatter(one_chip):
+    """The assembly's face sums compile for a v5e as gathers: the gradient
+    of the benchmark's 70³ cavity on 10 slabs (f64, as the assembly runs)
+    holds no scatter instruction."""
+    asm = CavityAssembly(CavityMesh.cube(70, 10), nu=1e-3)
+    p = jax.ShapeDtypeStruct((10, asm.m), jnp.float64, sharding=one_chip)
+    hlo = jax.jit(asm.grad).lower(p).compile().as_text()
+    assert re.search(r"[ )]gather\(", hlo)
+    assert not re.findall(r"[ )]scatter\(", hlo)
